@@ -145,6 +145,8 @@ TABLES = dict(fig3=fig3, fig4a=fig4a, fig4b=fig4b, fig5a=fig5a, fig5b=fig5b,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or list(TABLES)
     print("table,name,us_per_call,derived")
     for n in names:

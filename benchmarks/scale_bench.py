@@ -25,9 +25,9 @@ Two sections, one JSON document (``BENCH_scale.json``):
   twin) at M = min(10k, max fleet): channel-keyed speculation must land
   (nonzero plan-ahead hits), win wall time, and stay bitwise against the
   synchronous twin — the configuration PR 7 had to disable outright.
-  Runs in a fresh interpreter (``--dynamic-only`` spawn) so its sync
-  twin carries the cold compile like every per-M pipelined comparison,
-  without warming the parent's caches under the traced-overhead rows.
+  The pair shares one fresh executable cache, so its sync twin carries
+  the cold in-process compile like every per-M pipelined comparison,
+  without warming the process-wide cache under the traced-overhead rows.
 
 * **traced** — the same online runs with the full telemetry stack
   attached (event tracer + metrics registry + per-request lifecycle
@@ -81,7 +81,7 @@ def run_online_scale(M: int, load_hz: float, seed: int, arrival_seed: int,
                      plan_workers: int = 0,
                      plan_depth: int = 1,
                      channel: str | None = None,
-                     telemetry=None):
+                     telemetry=None, cache=None):
     """One sustained-load run at fleet size M through the batched loop.
 
     Returns ``(row, result)`` — the JSON row plus the raw
@@ -92,13 +92,14 @@ def run_online_scale(M: int, load_hz: float, seed: int, arrival_seed: int,
     ``channel`` names a :func:`~repro.core.make_channel` kind for a
     channel-aware run (the dynamic-channel pipelined section exercises
     digest-keyed speculation); ``plan_depth`` sets the speculation chain
-    depth when ``plan_workers > 0``."""
+    depth when ``plan_workers > 0``.  ``cache`` gives the run's planner
+    service that executable cache instead of the process-wide one."""
     from repro.core import (OnlineScheduler, PlannerService, make_channel,
                             poisson_arrivals)
     profile, edge, fleet = _build(M, seed)
     rate = load_hz * M
     arrivals = poisson_arrivals(M, rate, fleet, seed=arrival_seed)
-    service = PlannerService(profile, edge)
+    service = PlannerService(profile, edge, cache=cache)
     sched = OnlineScheduler(profile, fleet, edge, policy=policy,
                             keep_frac=0.7, service=service,
                             batch_window=batch_window,
@@ -275,60 +276,37 @@ def run_planning_scale(M: int, cohort_size: int, seed: int) -> dict:
     )
 
 
-_DYNAMIC_MARK = "DYNAMIC_JSON: "
-
-
 def run_dynamic_channel(m_dyn: int, load: float, seed: int,
                         arrival_seed: int, policy: str,
                         batch_window: float, plan_workers: int,
                         plan_depth: int) -> dict:
     """The dynamic-channel pipelined pair: a SharedUplink channel-aware
-    sync run and its plan-ahead twin.  PR 7 disabled speculation outright
-    under a dynamic channel-aware snapshot; the channel-keyed digest
-    re-enables it, so this run must show nonzero plan-ahead hits AND a
-    wall-time win, still bitwise against the synchronous twin.  Meant to
-    run in a FRESH process (``--dynamic-only``) so the sync twin carries
-    the cold compile — the same convention as the per-M pipelined rows
+    sync run and its plan-ahead twin.  Speculation under a dynamic
+    channel-aware snapshot is keyed on the channel digest, so this run
+    must show nonzero plan-ahead hits AND a wall-time win, still bitwise
+    against the synchronous twin.  The pair
+    shares one fresh executable cache, so the sync twin carries the cold
+    in-process compile — the same convention as the per-M pipelined rows
     (overlapping first-dispatch compiles is the win) — without warming
-    the parent's caches and skewing its traced-overhead rows."""
-    rd, resd = run_online_scale(m_dyn, load, seed, arrival_seed,
-                                policy=policy, batch_window=batch_window,
-                                channel="shared")
-    rdp, resdp = run_online_scale(m_dyn, load, seed, arrival_seed,
-                                  policy=policy, batch_window=batch_window,
-                                  plan_workers=plan_workers,
-                                  plan_depth=plan_depth,
-                                  channel="shared")
+    the process-wide cache the other sections use."""
+    from repro.core.jdob import ExecutableCache
+    cache = ExecutableCache()
+    try:
+        rd, resd = run_online_scale(m_dyn, load, seed, arrival_seed,
+                                    policy=policy, batch_window=batch_window,
+                                    channel="shared", cache=cache)
+        rdp, resdp = run_online_scale(m_dyn, load, seed, arrival_seed,
+                                      policy=policy,
+                                      batch_window=batch_window,
+                                      plan_workers=plan_workers,
+                                      plan_depth=plan_depth,
+                                      channel="shared", cache=cache)
+    finally:
+        cache.shutdown()
     rdp["parity"] = _same_result(resd, resdp)
     rdp["pipeline_speedup"] = (rd["wall_s"] / rdp["wall_s"]
                                if rdp["wall_s"] > 0 else 0.0)
     return dict(sync=rd, pipelined=rdp)
-
-
-def _spawn_dynamic(args, arrival_seed: int) -> dict | None:
-    """Run the dynamic-channel section in a fresh interpreter and parse
-    its marker line (falls back to in-process on spawn failure)."""
-    import subprocess
-    cmd = [sys.executable, os.path.abspath(__file__), "--dynamic-only",
-           "--load", str(args.load), "--policy", args.policy,
-           "--batch-window", str(args.batch_window),
-           "--seed", str(args.seed), "--arrival-seed", str(arrival_seed),
-           "--plan-workers", str(args.plan_workers),
-           "--plan-depth", str(args.plan_depth),
-           "--fleet-sizes"] + [str(m) for m in args.fleet_sizes]
-    try:
-        out = subprocess.run(cmd, capture_output=True, text=True,
-                             check=True).stdout
-        for line in out.splitlines():
-            if line.startswith(_DYNAMIC_MARK):
-                return json.loads(line[len(_DYNAMIC_MARK):])
-    except (subprocess.SubprocessError, OSError, ValueError) as e:
-        print(f"dynamic-channel subprocess failed ({e}); "
-              f"running in-process (sync twin will be warm)")
-    return run_dynamic_channel(min(10_000, max(args.fleet_sizes)),
-                               args.load, args.seed, arrival_seed,
-                               args.policy, args.batch_window,
-                               args.plan_workers, args.plan_depth)
 
 
 def main(argv=None) -> int:
@@ -362,10 +340,6 @@ def main(argv=None) -> int:
                     help="machine-readable output path ('' disables)")
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny axes for CI (wiring + gate only)")
-    ap.add_argument("--dynamic-only", action="store_true",
-                    help="(internal) run just the dynamic-channel pair "
-                         "and emit its JSON marker line — spawned in a "
-                         "fresh process so the sync twin stays cold")
     args = ap.parse_args(argv)
     arrival_seed = args.seed if args.arrival_seed is None else \
         args.arrival_seed
@@ -380,13 +354,8 @@ def main(argv=None) -> int:
         if args.cohort_size == ap.get_default("cohort_size"):
             args.cohort_size = 8
 
-    if args.dynamic_only:
-        dyn = run_dynamic_channel(min(10_000, max(args.fleet_sizes)),
-                                  args.load, args.seed, arrival_seed,
-                                  args.policy, args.batch_window,
-                                  args.plan_workers, args.plan_depth)
-        print(_DYNAMIC_MARK + json.dumps(dyn))
-        return 0
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"{'M':>7} {'rate/s':>8} {'flushes':>7} {'batch μ/max':>11} "
           f"{'viol':>6} {'goodput/s':>9} {'J/req':>8} {'p50/p99 ms':>12} "
@@ -450,7 +419,10 @@ def main(argv=None) -> int:
 
     dynamic = None
     if args.plan_workers > 0:
-        dynamic = _spawn_dynamic(args, arrival_seed)
+        dynamic = run_dynamic_channel(min(10_000, max(args.fleet_sizes)),
+                                      args.load, args.seed, arrival_seed,
+                                      args.policy, args.batch_window,
+                                      args.plan_workers, args.plan_depth)
         rdp = dynamic["pipelined"]
         h, ms = rdp["plan_ahead_hits"], rdp["plan_ahead_misses"]
         print(f"\ndynamic channel (shared uplink) at M={rdp['users']}: "

@@ -283,6 +283,8 @@ def main(argv=None) -> int:
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny scenario set for CI (wiring + gate only)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.dry_run:
         # never clobber the committed baseline snapshots (the regression
         # gate's reference) with a tiny dry-run doc: divert default

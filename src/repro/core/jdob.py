@@ -89,6 +89,16 @@ class Schedule:
         the plan was given."""
         return self.t_free_end - self.gpu_busy
 
+    @property
+    def edge_share(self) -> float:
+        """Each offloader's share of what ``energy`` charges beyond
+        ``per_user_energy``: the edge's ψ·f_e² plus the float32 grid's
+        rounding against the float64 per-user sum, so that per-user
+        accounting adds up to ``energy``."""
+        n = self.batch_size
+        spent = float(np.sum(self.per_user_energy, dtype=np.float64))
+        return (self.energy - spent) / n if n else 0.0
+
 
 def _prep_blocks(profile: TaskProfile, edge: EdgeProfile) -> dict:
     """Per-block constants shared by every group (scaled to GHz/s/J)."""
@@ -969,6 +979,68 @@ def fused_scan_viable(levels: int) -> bool:
 _OG_SCAN_STATICS = ("n_partitions", "sort_keys", "mode", "width", "eps",
                     "beam", "growth", "cap", "anchor_mode", "prev_split")
 
+# IEEE-754 binary64 as int64 bit patterns.  The fused scan keeps its
+# energies and cursors in this form: for non-negative values the integer
+# order is the float order and integer arithmetic is exact on every
+# backend.  The v5e has no native float64; its emulated float64 neither
+# holds nor adds binary64 values exactly, which broke the scan's bit
+# parity with the host fold there.
+_MANT = (1 << 52) - 1
+_HIDDEN = 1 << 52
+_INF_BITS = 0x7FF0000000000000
+
+
+def _f64_add_bits(a, b):
+    """``a + b`` in binary64, rounded to nearest even, for non-negative
+    operands given and returned as int64 bit patterns (+inf stays +inf)."""
+    a, b = jnp.maximum(a, b), jnp.minimum(a, b)
+    ea, eb = a >> 52, b >> 52
+    # significands with the hidden bit and 3 guard bits
+    ma = jnp.where(ea > 0, (a & _MANT) | _HIDDEN, a & _MANT) << 3
+    mb = jnp.where(eb > 0, (b & _MANT) | _HIDDEN, b & _MANT) << 3
+    ea, eb = jnp.maximum(ea, 1), jnp.maximum(eb, 1)
+    d = jnp.minimum(ea - eb, 60)
+    sticky = (mb & ((jnp.int64(1) << d) - 1)) != 0
+    s = ma + ((mb >> d) | sticky)
+    carry = s >= (jnp.int64(1) << 56)
+    s = jnp.where(carry, (s >> 1) | (s & 1), s)
+    e = ea + carry
+    m, low = s >> 3, s & 7
+    m = m + ((low > 4) | ((low == 4) & ((m & 1) == 1)))
+    over = m >= (jnp.int64(1) << 53)
+    m = jnp.where(over, m >> 1, m)
+    e = e + over
+    bits = jnp.where(m >= _HIDDEN, e << 52, 0) | (m & _MANT)
+    return jnp.where((a >= _INF_BITS) | (e >= 2047), _INF_BITS, bits)
+
+
+def _f32_to_f64_bits(x):
+    """Exact binary64 bit patterns of non-negative float32 values."""
+    u = jax.lax.bitcast_convert_type(x, jnp.int32)
+    e, m = u >> 23, (u & 0x7FFFFF).astype(jnp.int64)
+    # subnormal: normalize on the leading bit's position p
+    p = (31 - jax.lax.clz(jnp.maximum(m, 1).astype(jnp.int32))
+         ).astype(jnp.int64)
+    sub = ((p + (1023 - 149)) << 52) | ((m << (52 - p)) & _MANT)
+    norm = ((e.astype(jnp.int64) + (1023 - 127)) << 52) | (m << 29)
+    return jnp.where(e == 255, _INF_BITS,
+                     jnp.where(e > 0, norm, jnp.where(m > 0, sub, 0)))
+
+
+def _f64_bits_to_f32(b):
+    """Non-negative binary64 bit patterns rounded to nearest-even
+    float32 — what ``astype(float32)`` gives on an IEEE backend."""
+    e, m = b >> 52, b & _MANT
+    e32 = e - (1023 - 127)
+    sig = jnp.where(e > 0, m | _HIDDEN, m)
+    sh = jnp.minimum(29 + jnp.maximum(1 - e32, 0), 62)
+    q, rem = sig >> sh, sig & ((jnp.int64(1) << sh) - 1)
+    half = jnp.int64(1) << (sh - 1)
+    q = q + ((rem > half) | ((rem == half) & ((q & 1) == 1)))
+    out = jnp.where(e32 > 0, (e32 - 1) << 23, 0) + q
+    out = jnp.where((e == 2047) | (out >= 0x7F800000), 0x7F800000, out)
+    return jax.lax.bitcast_convert_type(out.astype(jnp.int32), jnp.float32)
+
 
 @functools.partial(jax.jit, static_argnames=_OG_SCAN_STATICS)
 def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
@@ -978,31 +1050,33 @@ def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
              anchor_mode, prev_split):
     """The grouping DP's level loop as one ``lax.scan`` over levels.
 
-    MUST be traced and executed under ``jax.experimental.enable_x64()``
+    MUST be traced and executed under ``jax.enable_x64(True)``
     (see :func:`og_plan_fused`): the DP state tables and the dominance
-    sweep run in float64 to match the host DP's accumulation bit for bit,
-    while every segment solve stays in the float32 :func:`_solve_group`
-    math (python scalars are weak types, so enabling x64 does not promote
-    the inlined kernel).
+    sweep hold binary64 values as int64 bit patterns and add them with
+    :func:`_f64_add_bits`, which matches the host DP's float64
+    accumulation bit for bit on any backend, while every segment solve
+    stays in the float32 :func:`_solve_group` math (python scalars are
+    weak types, so enabling x64 does not promote the inlined kernel).
 
     State layout — the frontier lives on device as fixed-width masked
-    rows: ``e_tab``/``tf_tab`` (L+1, W) float64 energies / threaded
-    cursors, ``sp_tab``/``si_tab`` (L+1, W) int32 backpointers (split
-    level, state slot), ``va_tab`` (L+1, W) occupancy mask (valid slots
-    are always a prefix; W == 1 is the prefix DP).  ``bounds`` (L+1,)
+    rows: ``e_tab``/``tf_tab`` (L+1, W) binary64 bit patterns of the
+    energies / threaded cursors (all non-negative), ``sp_tab``/``si_tab``
+    (L+1, W) int32 backpointers (split level, state slot), ``va_tab``
+    (L+1, W) occupancy mask (valid slots are always a prefix; W == 1 is
+    the prefix DP).  ``bounds`` (L+1,)
     generalizes the level axis: ``arange(M+1)`` for the user-level OG DP,
     the atom boundaries for the cohort merge DP (level j covers users
     ``[bounds[i], bounds[j])``).  Levels ``j <= start`` (incremental
     resume) and ``j > n_active`` (bucket padding) pass through unchanged.
-    ``e_all`` rows carry the precomputed float64 all-local fallback
-    energies (host ``_reconstruct`` semantics).  One ys row per level is
-    the ONLY materialization — the host backtracks the winning chain from
-    it and re-solves just that chain's segments."""
+    ``e_all`` rows carry the bit patterns of the precomputed float64
+    all-local fallback energies (host ``_reconstruct`` semantics).  One
+    ys row per level is the ONLY materialization — the host backtracks
+    the winning chain from it and re-solves just that chain's
+    segments."""
     L = bounds.shape[0] - 1
     W = width
     Mp = c_user["T"].shape[0]
-    f64 = jnp.float64
-    INF64 = jnp.asarray(jnp.inf, f64)
+    INF64 = jnp.asarray(_INF_BITS, jnp.int64)
     i_vec = jnp.arange(L, dtype=jnp.int32)
     slot = jnp.arange(W, dtype=jnp.int32)
 
@@ -1033,35 +1107,35 @@ def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
         seg_ok = (i_vec < j) & (i_vec >= j - window) & \
             ~((j - i_vec > 1) & (ln > size_cap))
         st_e, st_tf = e_tab[:L], tf_tab[:L]
-        cand_ok = seg_ok[:, None] & va_tab[:L] & jnp.isfinite(st_e)
+        cand_ok = seg_ok[:, None] & va_tab[:L] & (st_e < INF64)
         # all (state slot, candidate split) segment solves of this level
         e32, t32 = jax.vmap(solve_seg)(
             jnp.broadcast_to(lo[:, None], (L, W)).reshape(-1),
             jnp.broadcast_to(ln[:, None], (L, W)).reshape(-1),
-            st_tf.astype(jnp.float32).reshape(-1))
+            _f64_bits_to_f32(st_tf).reshape(-1))
         e32 = e32.reshape(L, W)
         t32 = t32.reshape(L, W)
         # host _reconstruct's float64 all-local fallback: always feasible,
         # replaces the grid winner when cheaper-or-equal, passes the
         # cursor through unchanged
-        e_seg = e32.astype(f64)
-        all_local = ~jnp.isfinite(e_seg) | (eall_row[:, None] <= e_seg)
+        e_seg = _f32_to_f64_bits(e32)
+        all_local = (e_seg >= INF64) | (eall_row[:, None] <= e_seg)
         seg_e = jnp.where(all_local, eall_row[:, None], e_seg)
-        seg_tf = jnp.where(all_local, st_tf, t32.astype(f64))
-        cand_e = jnp.where(cand_ok, st_e + seg_e, INF64)
+        seg_tf = jnp.where(all_local, st_tf, _f32_to_f64_bits(t32))
+        cand_e = jnp.where(cand_ok, _f64_add_bits(st_e, seg_e), INF64)
         dflt_sp = (j - 1) if prev_split else jnp.zeros((), jnp.int32)
 
         if mode == "prefix":
             ce = cand_e[:, 0]
             bi = jnp.argmin(ce).astype(jnp.int32)   # first min == smallest i
-            feas = jnp.isfinite(ce[bi])
+            feas = ce[bi] < INF64
             row_e = jnp.where(feas, ce[bi], INF64)[None]
             row_tf = jnp.where(feas, seg_tf[bi, 0], t_free0)[None]
             row_sp = jnp.where(feas, bi, dflt_sp)[None].astype(jnp.int32)
             row_si = jnp.zeros((1,), jnp.int32)
             row_va = jnp.ones((1,), bool)
             anc_j = jnp.zeros((), jnp.int32)
-            n_in = jnp.sum(jnp.isfinite(ce)).astype(jnp.int32)
+            n_in = jnp.sum(ce < INF64).astype(jnp.int32)
             n_front = jnp.ones((), jnp.int32)
             inserted = jnp.zeros((), bool)
             overflow = jnp.zeros((), bool)
@@ -1070,7 +1144,7 @@ def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
             ftf = jnp.where(cand_ok, seg_tf, INF64).reshape(-1)
             fsp = jnp.broadcast_to(i_vec[:, None], (L, W)).reshape(-1)
             fsi = jnp.broadcast_to(slot[None, :], (L, W)).reshape(-1)
-            fin = jnp.isfinite(fe)
+            fin = fe < INF64
             # _pareto_sweep's sort key (energy, t_free, split, state):
             # flat order is already (split, state) lexicographic, so two
             # stable sorts finish the key
@@ -1086,9 +1160,14 @@ def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
                 pmin = jnp.concatenate([INF64[None], cm[:-1]])
                 keep = sfin & (stf < pmin)
             else:
+                # the epsilon threshold is one float64 product: exact on
+                # an IEEE backend, emulated (not bit-exact) on the v5e
+                f64 = functools.partial(jax.lax.bitcast_convert_type,
+                                        new_dtype=jnp.float64)
+
                 def sweep(btf, x):
                     tf_, ok = x
-                    k = ok & (tf_ < btf * (1.0 - eps))
+                    k = ok & (f64(tf_) < f64(btf) * (1.0 - eps))
                     return jnp.where(k, tf_, btf), k
                 _, keep = jax.lax.scan(sweep, INF64, (stf, sfin))
             n_in = jnp.sum(sfin).astype(jnp.int32)
@@ -1133,10 +1212,10 @@ def _og_scan(c_user, blocks, f_sweep, part_mask, bounds, e_all, t_free0,
                 a_stf = jnp.take_along_axis(seg_tf, a_sl[:, None], 1)[:, 0]
                 a_va = jnp.take_along_axis(va_tab[:L], a_sl[:, None],
                                            1)[:, 0]
-                a_ce = jnp.where(seg_ok & a_va & jnp.isfinite(ae),
-                                 ae + a_se, INF64)
+                a_ce = jnp.where(seg_ok & a_va & (ae < INF64),
+                                 _f64_add_bits(ae, a_se), INF64)
                 ab = jnp.argmin(a_ce).astype(jnp.int32)
-                a_found = jnp.isfinite(a_ce[ab])
+                a_found = a_ce[ab] < INF64
                 a_si = a_sl[ab]
                 match = row_va & (row_sp == ab) & (row_si == a_si)
                 ins = (~empty) & a_found & (~jnp.any(match))
@@ -1269,6 +1348,8 @@ def og_plan_fused(planner: BatchedPlanner, sorted_fleet: DeviceFleet, *,
         # a resumed host frontier wider than the device buffer cannot be
         # represented — let the caller fall back without a dispatch
         return FusedScanResult([], [], [], True, W, widen0)
+    if not t_free >= 0.0:
+        raise ValueError(f"the fused scan needs t_free >= 0, got {t_free}")
     e_t = np.full((L + 1, W), np.inf)
     tf_t = np.full((L + 1, W), float(t_free))
     sp_t = np.zeros((L + 1, W), np.int32)
@@ -1307,16 +1388,17 @@ def og_plan_fused(planner: BatchedPlanner, sorted_fleet: DeviceFleet, *,
     key = ("og_scan",) + tuple(sorted(statics.items()))
     t0 = time.perf_counter_ns()
     # the x64 scope covers compile AND execution: the compiled signature
-    # carries float64 tables, and input conversion follows the ambient
-    # config, so calling outside the scope would downcast them
-    with jax.experimental.enable_x64():
+    # carries int64 tables, and input conversion follows the ambient
+    # config, so calling outside the scope would downcast them.  Float64
+    # values cross to the device as their bit patterns.
+    bits = lambda a: jnp.asarray(np.asarray(a, np.float64).view(np.int64))
+    with jax.enable_x64(True):
         args = (c_user, planner.blocks, planner.f_sweep, planner.part_mask,
-                jnp.asarray(bounds), jnp.asarray(e_all),
-                jnp.asarray(np.float64(t_free)),
+                jnp.asarray(bounds), bits(e_all), bits(t_free),
                 jnp.asarray(np.int32(start)), jnp.asarray(np.int32(n_act)),
                 jnp.asarray(np.int32(L if window is None else window)),
                 jnp.asarray(np.int32(M if size_cap is None else size_cap)),
-                jnp.asarray(e_t), jnp.asarray(tf_t), jnp.asarray(sp_t),
+                bits(e_t), bits(tf_t), jnp.asarray(sp_t),
                 jnp.asarray(si_t), jnp.asarray(va_t), jnp.asarray(anc_np),
                 jnp.asarray(np.int32(width0)),
                 jnp.asarray(np.int32(widen0)))
@@ -1325,6 +1407,7 @@ def og_plan_fused(planner: BatchedPlanner, sorted_fleet: DeviceFleet, *,
             stats=planner.stats)
         planner.stats.dispatches += 1
         ys = {k: np.asarray(v) for k, v in exe(*args).items()}
+    ys["e"], ys["tf"] = ys["e"].view(np.float64), ys["tf"].view(np.float64)
     planner.stats.record_fused_scan(time.perf_counter_ns() - t0,
                                     compiled=compiled)
 

@@ -883,8 +883,7 @@ class OnlineScheduler:
         np.add.at(self.per_user_energy, idx, s.per_user_energy)
         if s.offload.any():
             # edge energy attributed evenly across the batch
-            np.add.at(self.per_user_energy, idx[s.offload],
-                      s.terms["edge"] / s.offload.sum())
+            np.add.at(self.per_user_energy, idx[s.offload], s.edge_share)
         gpu_free = self._book(now, s)
         ev = FlushEvent(now, q, idx, s, gpu_free, late,
                         seq=len(self._batches),
@@ -944,8 +943,7 @@ class OnlineScheduler:
         done_off = now + float(s.t_free_end)
         g_start = now + float(s.gpu_start)
         v_tot = float(self.profile.v()[-1])
-        edge_share = (float(s.terms.get("edge", 0.0)) / n_off if n_off
-                      else 0.0)
+        edge_share = s.edge_share
         record = (self.telemetry.record_request
                   if self.telemetry.request_log else None)
         for i, a in enumerate(q):
@@ -990,12 +988,11 @@ class OnlineScheduler:
         np.add.at(self.per_user_energy, idx, -old.per_user_energy)
         if old.offload.any():
             np.add.at(self.per_user_energy, idx[old.offload],
-                      -old.terms["edge"] / old.offload.sum())
+                      -old.edge_share)
         s = schedule if schedule is not None else self._plan_event(ev, t_free)
         np.add.at(self.per_user_energy, idx, s.per_user_energy)
         if s.offload.any():
-            np.add.at(self.per_user_energy, idx[s.offload],
-                      s.terms["edge"] / s.offload.sum())
+            np.add.at(self.per_user_energy, idx[s.offload], s.edge_share)
             gpu_free = ev.time + s.t_free_end
         else:
             gpu_free = max(idle_gpu_free if idle_gpu_free is not None
@@ -1548,7 +1545,7 @@ def simulate_online_reference(arrivals: list[OnlineArrival],
         s: Schedule = plan_flush(sub, max(gpu_free - now, 0.0))
         per_user[idx] += s.per_user_energy
         if s.offload.any():
-            per_user[idx[s.offload]] += s.terms["edge"] / s.offload.sum()
+            per_user[idx[s.offload]] += s.edge_share
             gpu_free = now + s.t_free_end
         batches.append(int(s.offload.sum()))
         flush_times.append(now)

@@ -395,7 +395,7 @@ def aggregate_counter_fields(cls, objs: Iterable[Any],
 
 
 # ---------------------------------------------------------------------------
-# process-wide runtime events (e.g. kernels/compat fallback warnings)
+# process-wide runtime events
 # ---------------------------------------------------------------------------
 _RUNTIME_EVENTS: dict[str, dict] = {}
 _RUNTIME_LOCK = threading.Lock()
@@ -406,9 +406,8 @@ def note_runtime_event(key: str, message: str,
     """Record a process-wide runtime event (idempotent key, counted).
 
     Used by paths that cannot reach a per-run :class:`Telemetry`
-    instance — e.g. the one-time Pallas compat fallbacks in
-    ``kernels/compat.py`` — so dropped hints show up in run metrics
-    instead of only on stderr."""
+    instance, so what they report shows up in run metrics instead of
+    only on stderr."""
     with _RUNTIME_LOCK:
         ev = _RUNTIME_EVENTS.setdefault(
             key, {"count": 0, "message": message, "category": category})

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 _NEG_INF = -1e30
 
 
@@ -94,7 +92,7 @@ def decode_attention_kernel(q, k, v, pos, *, ring: bool = False,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, 1, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos_arr, q, k, v)

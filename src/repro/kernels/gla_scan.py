@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 
 def _kernel(q_ref, k_ref, v_ref, ld_ref, y_ref, s_out_ref, state_ref, *,
             chunk: int, nc: int):
@@ -90,7 +88,7 @@ def gla_scan(q, k, v, log_decay, *, chunk: int = 256,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, ld)

@@ -1,14 +1,13 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Shape plumbing: the model layers use (B, S, H, hd) GQA tensors; the kernels
-take head-folded (B·H, S, hd).  On this CPU container the kernels run with
-``interpret=True``; on TPU pass ``interpret=False`` (the default resolves
-via :func:`default_interpret`).
+take head-folded (B·H, S, hd).  The kernels compile for Mosaic on a TPU
+and run with ``interpret=True`` everywhere else (the default resolves via
+:func:`default_interpret`).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -17,22 +16,11 @@ import numpy as np
 from .decode_attention import decode_attention_kernel
 from .flash_attention import flash_attention
 from .gla_scan import gla_scan
-from .jdob_sweep import jdob_sweep_kernel
+from .jdob_sweep import LANES, jdob_sweep_kernel
 
 
 def default_interpret() -> bool:
-    """Interpret-mode default: CPU/GPU containers interpret, TPU compiles.
-    ``JAX_PALLAS_INTERPRET=1`` (or ``0``) overrides either way — nightly CI
-    sets it explicitly so the compiled-path plumbing (``compat.
-    tpu_compiler_params`` and the ``dimension_semantics`` hints) is at
-    least exercised deterministically in interpret mode until real-TPU
-    validation lands (see ROADMAP).  When the resolved compiler-params
-    class cannot honor ``dimension_semantics``, ``compat`` now emits a
-    one-time ``RuntimeWarning`` instead of silently dropping the hint —
-    compiled-mode perf regressions get a signal."""
-    env = os.environ.get("JAX_PALLAS_INTERPRET", "").strip().lower()
-    if env:                      # empty/unset falls through to the default
-        return env not in ("0", "false", "no")
+    """Kernels compile for Mosaic on a TPU and interpret everywhere else."""
     return jax.default_backend() != "tpu"
 
 
@@ -87,14 +75,11 @@ def gla_scan_op(q, k, v, log_decay, *, chunk=256, interpret=None):
     return y, s.reshape(b, h, dk, dv)
 
 
-def jdob_sweep_op(profile, fleet, edge, t_free=0.0, rho=0.03e9,
-                  interpret=None):
-    """The paper's (ñ × f_e) energy grid on-device.  Host does Alg.1's
-    sort; kernel does Alg.2's sweep.  Same (GHz, s, J) scaling as
-    :mod:`repro.core.jdob`; returns an (N+1, K) float32 grid whose row N is
-    +inf (local branch handled in closed form by the caller)."""
+def jdob_sweep_inputs(profile, fleet, edge, t_free=0.0, rho=0.03e9):
+    """Host side of the sweep (Alg. 1's per-ñ sort) in the (GHz, s, J)
+    scaling of :mod:`repro.core.jdob`: the argument tuple of
+    :func:`~repro.kernels.jdob_sweep.jdob_sweep_kernel`."""
     from repro.core.jdob import _GHZ, make_f_sweep
-    interpret = default_interpret() if interpret is None else interpret
     N = profile.N
     M = fleet.M
     v = profile.v() / _GHZ
@@ -142,15 +127,30 @@ def jdob_sweep_op(profile, fleet, edge, t_free=0.0, rho=0.03e9,
         fmx[nt] = (fleet.f_max / _GHZ)[order]
         scal[nt] = [phi_b[nt], phi_s[nt], psi_b[nt], psi_s[nt], v[nt], u[nt],
                     t_free, 0.0]
-    f_rows = np.broadcast_to(fsw.astype(np.float32), (N + 1, K)).copy()
+    f_rows = np.broadcast_to(fsw.astype(np.float32), (N + 1, K))
 
+    # users on lanes, padded to whole vregs: a padded user never joins the
+    # batch (th = +inf) and contributes zero energy
+    Mp = -(-M // LANES) * LANES
+    pad = lambda a, fill=0.0: jnp.asarray(
+        np.pad(a, ((0, 0), (0, Mp - M)), constant_values=fill)[:, None, :])
+    return (pad(th, np.inf), pad(sufft), pad(our), pad(eup), pad(elo),
+            pad(zet), pad(kus), pad(fmn), pad(fmx),
+            jnp.asarray(scal[:, None, :]), jnp.asarray(f_rows[:, :, None]))
+
+
+def jdob_sweep_op(profile, fleet, edge, t_free=0.0, rho=0.03e9,
+                  interpret=None):
+    """The paper's (ñ × f_e) energy grid on-device.  Host does Alg.1's
+    sort; kernel does Alg.2's sweep.  Returns an (N+1, K) float32 grid
+    whose row N is +inf (local branch handled in closed form by the
+    caller)."""
+    interpret = default_interpret() if interpret is None else interpret
     grid = jdob_sweep_kernel(
-        jnp.asarray(th), jnp.asarray(sufft), jnp.asarray(our),
-        jnp.asarray(eup), jnp.asarray(elo), jnp.asarray(zet),
-        jnp.asarray(kus), jnp.asarray(fmn), jnp.asarray(fmx),
-        jnp.asarray(scal), jnp.asarray(f_rows), interpret=interpret)
-    grid = np.array(grid)
-    grid[N] = np.inf
+        *jdob_sweep_inputs(profile, fleet, edge, t_free=t_free, rho=rho),
+        interpret=interpret)
+    grid = np.array(grid[:, :, 0])
+    grid[profile.N] = np.inf
     return grid
 
 

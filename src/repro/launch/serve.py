@@ -1,6 +1,7 @@
 """Co-inference serving driver: the paper's system end to end.
 
-Builds a reduced model, an M-user fleet with deadlines, runs the J-DOB
+Builds a model (reduced by default, ``--full-width`` for the published
+widths), an M-user fleet with deadlines, runs the J-DOB
 scheduler, executes the partitioned/batched plan on the real model, and
 verifies outputs equal the monolithic forward.
 
@@ -40,6 +41,7 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS
@@ -47,9 +49,24 @@ from repro.core import (PlannerService, Telemetry, local_computing,
                         make_channel, make_edge_profile, make_fleet,
                         profile_from_arch)
 from repro.core.telemetry import TID_RUN
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serving import (CoInferenceServer, MultiTenantServer, Request,
                            TenantModel)
+
+
+def _build_model(args, seed: int):
+    """The served model: the registered architecture at its published
+    widths and depth with bf16 weights (``--full-width``), or its reduced
+    smoke variant with float32 weights."""
+    key = jax.random.PRNGKey(seed)
+    if args.full_width:
+        cfg = ARCHS[args.arch]
+        params = jax.jit(lambda k: init_params(cfg, k, jnp.bfloat16))(key)
+    else:
+        cfg = ARCHS[args.arch].reduced()
+        params = init_params(cfg, key)
+    return cfg, params
 
 
 def _build_channel(args):
@@ -60,11 +77,28 @@ def _build_channel(args):
                         seed=args.seed)
 
 
-def _verify(report_logits, executor, reqs) -> float:
-    import jax.numpy as jnp
+#: co-inference vs monolithic logits, relative to max |logit|.  Both sides
+#: run the same float32 layer steps, only at different batch sizes, and
+#: XLA may tile a contraction differently per batch shape, which reorders
+#: float32 sums.  Measured: 7.1e-7 at minitron-4b widths on a TPU v5e, up
+#: to 7e-7 on the CPU at reduced widths; one bf16 pass per matmul (the
+#: v5e's default precision) gives 5.4e-3, which this bound rejects.
+LOGIT_RTOL = 1e-5
+
+
+def _verify(report_logits, executor, reqs, rows=slice(None)) -> float:
+    """max |Δlogit| of the served rows against the monolithic forward;
+    raises past :data:`LOGIT_RTOL` of the logits' scale."""
     want = np.asarray(executor.full_forward(
-        jnp.asarray(np.stack([r.tokens for r in reqs]))))
-    return float(np.abs(report_logits - want).max())
+        jnp.asarray(np.stack([r.tokens for r in reqs]))))[rows]
+    err = float(np.abs(report_logits[rows] - want).max())
+    scale = float(np.abs(want).max())
+    print(f"co-inference vs monolithic max |Δlogit| = {err:.2e} "
+          f"(max |logit| {scale:.2e}, bound {LOGIT_RTOL:.0e} of it)")
+    if not err <= LOGIT_RTOL * scale:
+        raise RuntimeError(f"co-inference logits differ from the "
+                           f"monolithic forward by {err:.3e}")
+    return err
 
 
 def _plan_latency_line(service) -> None:
@@ -146,11 +180,10 @@ def _serve_offline(server, fleet, profile, edge, reqs, args,
           f"(LC: {lc.energy:.4f} J, saving "
           f"{100 * (1 - report.energy / lc.energy):.1f}%)")
     err = _verify(report.logits, server.executor, reqs)
-    print(f"co-inference vs monolithic max |Δlogit| = {err:.2e}")
-    assert err < 1e-3
     _plan_latency_line(server.service)
     _finish_telemetry(telemetry, args, server.service, report.t_free_end)
-    return dict(energy=report.energy, lc=lc.energy, err=err)
+    return dict(energy=report.energy, lc=lc.energy, err=err,
+                partitions=report.partitions, batches=report.batch_sizes)
 
 
 def _serve_online(server, fleet, profile, edge, reqs, args,
@@ -197,8 +230,6 @@ def _serve_online(server, fleet, profile, edge, reqs, args,
               f"{report.realized_late} realized-late request(s), "
               f"{report.stagger_replans} stagger re-price(s)")
     err = _verify(report.logits, server.executor, reqs)
-    print(f"co-inference vs monolithic max |Δlogit| = {err:.2e}")
-    assert err < 1e-3
     if report.violations:
         # legitimate under tight --beta: requests past their point of no
         # return by the time the policy flushed — report, don't crash
@@ -213,19 +244,19 @@ def _serve_online(server, fleet, profile, edge, reqs, args,
                       report.gpu_busy_until)
     return dict(energy=report.energy, lc=lc.energy, err=err,
                 violations=report.violations,
-                n_flushes=len(report.flushes))
+                n_flushes=len(report.flushes),
+                partitions=[ev.schedule.partition for ev in report.flushes],
+                batches=[ev.schedule.batch_size for ev in report.flushes])
 
 
 def _serve_tenants(args, telemetry=None) -> dict:
     """N tenants with distinct profiles/deadlines on one shared GPU."""
-    import jax.numpy as jnp
     rng = np.random.default_rng(args.seed)
     arr_rng = (rng if args.arrival_seed is None
                else np.random.default_rng(args.arrival_seed))
     models, streams = [], []
     for t in range(args.tenants):
-        cfg = ARCHS[args.arch].reduced()
-        params = init_params(cfg, jax.random.PRNGKey(args.seed + t))
+        cfg, params = _build_model(args, args.seed + t)
         seq = args.seq + 8 * t                   # distinct task profiles
         profile = profile_from_arch(cfg, seq=seq)
         edge = make_edge_profile(profile)
@@ -278,12 +309,9 @@ def _serve_tenants(args, telemetry=None) -> dict:
               f"tax +{tr.preempt_tax_inflicted:.4f}/-"
               f"{tr.preempt_tax_suffered:.4f} J")
         if mask.any():
-            ex = server.executors[tid]
-            want = np.asarray(ex.full_forward(
-                jnp.asarray(np.stack([r.tokens for r in reqs]))))
-            err = float(np.abs(report.logits[tid][mask]
-                               - want[mask]).max())
-            max_err = max(max_err, err)
+            max_err = max(max_err, _verify(report.logits[tid],
+                                           server.executors[tid], reqs,
+                                           rows=mask))
     print(f"total energy: {report.energy:.4f} J  "
           f"violations={report.violations}  "
           f"preemptions={report.preemptions}  "
@@ -303,9 +331,6 @@ def _serve_tenants(args, telemetry=None) -> dict:
               f"{res.channel_replans} actualization replan(s), "
               f"{res.realized_late} realized-late request(s), "
               f"{res.stagger_replans} stagger re-price(s)")
-    print(f"co-inference vs monolithic max |Δlogit| = {max_err:.2e} "
-          f"(per tenant, served rows)")
-    assert max_err < 1e-3
     stats = server.service.stats()
     print(f"planner service family: {stats.dispatches} dispatches, "
           f"{stats.hits} cache hits / {stats.misses} compiles")
@@ -320,6 +345,10 @@ def _serve_tenants(args, telemetry=None) -> dict:
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the architecture at its published widths "
+                         "and depth with bf16 weights (default: its "
+                         "reduced smoke variant, float32 weights)")
     ap.add_argument("--users", type=int, default=6)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--beta", type=float, nargs=2, default=[2.0, 8.0])
@@ -424,13 +453,13 @@ def main(argv=None) -> dict:
                          "wall-clock numbers are under the explicitly "
                          "labeled 'wall_time' section")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     telemetry = (Telemetry() if args.trace or args.metrics_json else None)
     if args.tenants > 1:
         return _serve_tenants(args, telemetry)
 
-    cfg = ARCHS[args.arch].reduced()
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
+    cfg, params = _build_model(args, args.seed)
     profile = profile_from_arch(cfg, seq=args.seq)
     edge = make_edge_profile(profile)
     fleet = make_fleet(args.users, profile, edge, beta=tuple(args.beta),

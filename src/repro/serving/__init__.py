@@ -1,9 +1,9 @@
-from .engine import BlockwiseExecutor, flatten_layers
+from .engine import BlockwiseExecutor, layer_refs
 from .server import (CoInferenceServer, MultiTenantServeReport,
                      MultiTenantServer, OnlineServeReport, Request,
                      ServeReport, TenantModel, run_partitioned)
 
-__all__ = ["BlockwiseExecutor", "flatten_layers", "CoInferenceServer",
+__all__ = ["BlockwiseExecutor", "layer_refs", "CoInferenceServer",
            "MultiTenantServeReport", "MultiTenantServer",
            "OnlineServeReport", "Request", "ServeReport", "TenantModel",
            "run_partitioned"]

@@ -262,3 +262,51 @@ def test_jdob_sweep_backend_through_planner():
     assert core.energy == pallas.energy
     assert [list(g) for g in core.groups] == \
         [list(g) for g in pallas.groups]
+
+
+# ---------------------------------------------------------------------------
+# the scan's binary64-as-int64 arithmetic is IEEE-exact on any backend
+# ---------------------------------------------------------------------------
+
+def _binary64_operands():
+    rng = np.random.default_rng(0)
+    n = 20000
+    a = np.concatenate([
+        rng.random(n) * 3, rng.random(n),
+        np.exp(rng.uniform(-700, 700, n)), rng.random(n) * 1e-310,
+        1.0 + rng.integers(0, 2 ** 20, n) * 2.0 ** -52,
+        [0.0, 0.0, np.inf, 1.7e308, 5e-324]])
+    b = np.concatenate([
+        rng.random(n) * 3,
+        rng.random(n) * rng.choice([1e-16, 2.0 ** -53, 2.0 ** -52, 1.0], n),
+        np.exp(rng.uniform(-700, 700, n)), rng.random(n) * 1e-310,
+        np.full(n, 2.0 ** -53),                       # exact halfway ties
+        [0.0, 2.5, 1.0, 1.7e308, 5e-324]])
+    return a, b
+
+
+@pytest.mark.parametrize("op", ["add", "f32_to_f64", "f64_to_f32"])
+def test_binary64_bit_arithmetic_matches_ieee(op):
+    import jax
+    import jax.numpy as jnp
+    from repro.core.jdob import (_f32_to_f64_bits, _f64_add_bits,
+                                 _f64_bits_to_f32)
+    a, b = _binary64_operands()
+    bits = lambda x: jnp.asarray(x.view(np.int64))
+    with jax.enable_x64(True), np.errstate(over="ignore"):
+        if op == "add":
+            got = np.asarray(jax.jit(_f64_add_bits)(bits(a), bits(b)))
+            want = (a + b).view(np.int64)
+        elif op == "f32_to_f64":
+            x = np.concatenate([a, b]).astype(np.float32)
+            x = np.concatenate([x, [1e-45, 3e-39, np.float32(np.inf)]]
+                               ).astype(np.float32)
+            got = np.asarray(jax.jit(_f32_to_f64_bits)(jnp.asarray(x)))
+            want = x.astype(np.float64).view(np.int64)
+        else:
+            x = np.concatenate([a, b, [3e-39, 1e-46, 3.4028235677973366e38,
+                                       3.5e38]])
+            got = np.asarray(jax.jit(_f64_bits_to_f32)(bits(x))
+                             ).view(np.int32)
+            want = x.astype(np.float32).view(np.int32)
+    np.testing.assert_array_equal(got, want)

@@ -270,6 +270,21 @@ def test_property_per_user_energy_sums_to_total(M, rate, beta, seed, policy):
     np.testing.assert_allclose(r.energy, total_from_flushes, rtol=1e-9)
 
 
+@pytest.mark.parametrize("rate", [5.0, 282.0])
+def test_per_user_energy_adds_up_to_float32_grid_totals(rate):
+    """An offloaded flush's energy comes from the float32 planning grid;
+    its per-user terms plus the edge share must still add up to it, not
+    to the float64 re-sum of the float32 per-user terms (which differs by
+    float32 rounding, ~2e-8 relative, on these fleets)."""
+    fleet = make_fleet(2, PROF, EDGE, beta=3.0, seed=0)
+    sched = OnlineScheduler(PROF, fleet, EDGE, policy="slack", window=0.01)
+    sched.submit_many(poisson_arrivals(2, rate, fleet, seed=0))
+    r = sched.run()
+    assert any(ev.schedule.offload.any() for ev in sched.flushes)
+    total_from_flushes = sum(ev.schedule.energy for ev in sched.flushes)
+    np.testing.assert_allclose(r.energy, total_from_flushes, rtol=1e-9)
+
+
 # ---------------------------------------------------------------------------
 # offline bounds: subsetting fix
 # ---------------------------------------------------------------------------

@@ -342,7 +342,7 @@ def test_multi_tenant_result_sums_per_scheduler_counters():
 
 
 # ---------------------------------------------------------------------------
-# satellite 6: runtime events (kernels/compat fallback mirror)
+# satellite 6: runtime events registry
 # ---------------------------------------------------------------------------
 
 def test_runtime_events_registry_counts_and_snapshots():
@@ -357,23 +357,6 @@ def test_runtime_events_registry_counts_and_snapshots():
         ev["test.key"]["count"] = 99
         assert runtime_events()["test.key"]["count"] == 2
     finally:
-        reset_runtime_events()
-
-
-def test_compat_warn_once_mirrors_into_runtime_events():
-    from repro.kernels import compat
-    reset_runtime_events()
-    try:
-        key = "test-telemetry-unique"
-        compat._WARNED.discard(key)
-        with pytest.warns(RuntimeWarning):
-            compat._warn_once(key, "dropped a hint")
-        assert runtime_events()[f"kernels.compat.{key}"]["count"] == 1
-        # one-time: a second call neither warns nor recounts
-        compat._warn_once(key, "dropped a hint")
-        assert runtime_events()[f"kernels.compat.{key}"]["count"] == 1
-    finally:
-        compat._WARNED.discard(key)
         reset_runtime_events()
 
 

@@ -105,46 +105,3 @@ def test_jdob_sweep_kernel_vs_grid(M, beta, seed, t_free):
         assert np.unravel_index(np.argmin(got), got.shape) == \
             np.unravel_index(np.argmin(want), want.shape)
 
-
-# ---------------------------------------------------------------------------
-# TPU-compat fallback: dropped dimension_semantics must WARN, once
-# ---------------------------------------------------------------------------
-
-def test_tpu_compiler_params_warns_once_when_hint_dropped():
-    """When the resolved CompilerParams class cannot honor our kwargs the
-    shim must not silently drop the dimension_semantics hint (ROADMAP
-    TPU-path item (b)): first drop warns, repeats stay silent."""
-    import warnings
-    from repro.kernels import compat
-
-    compat._WARNED.clear()
-    # an impossible kwarg forces the TypeError fallback on any JAX version
-    with pytest.warns(RuntimeWarning, match="dimension_semantics"):
-        out = compat.tpu_compiler_params(
-            dimension_semantics=("parallel",),
-            definitely_not_a_real_kwarg=1)
-    assert out is None            # bogus kwarg rejected on the retry too
-    # one-time: the identical fallback is silent the second time
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        out = compat.tpu_compiler_params(
-            dimension_semantics=("parallel",),
-            definitely_not_a_real_kwarg=1)
-    assert out is None
-    compat._WARNED.clear()
-
-
-def test_tpu_compiler_params_happy_path_still_constructs():
-    """With honorable kwargs the shim behaves as before: either the
-    installed JAX builds the params object (no warning concerns) or the
-    version genuinely lacks the class and the shim returns None."""
-    from repro.kernels import compat
-    compat._WARNED.clear()
-    out = compat.tpu_compiler_params(dimension_semantics=("parallel",
-                                                          "arbitrary"))
-    from jax.experimental.pallas import tpu as pltpu
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is not None and out is not None:
-        assert isinstance(out, cls)
-    compat._WARNED.clear()
