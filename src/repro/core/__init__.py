@@ -1,8 +1,7 @@
 """The paper's contribution: J-DOB scheduling for multiuser co-inference."""
 from .telemetry import (NULL_TRACER, Histogram, MetricsRegistry, NullTracer,
                         Telemetry, Tracer, aggregate_counter_fields,
-                        note_runtime_event, runtime_events, tenant_tid,
-                        validate_events, validate_trace_file)
+                        tenant_tid, validate_events, validate_trace_file)
 from .task_model import TaskProfile, mobilenet_v2_profile, profile_from_arch
 from .channel import (CHANNEL_KINDS, ChannelModel, SharedUplink,
                       StaticChannel, TraceChannel, UploadSession, UploadSpan,
@@ -60,6 +59,6 @@ __all__ = [
     "MultiTenantScheduler", "ReplanRecord", "Tenant", "TenantResult",
     "min_offload_completion", "naive_fifo", "single_tenant_oracle",
     "NULL_TRACER", "Histogram", "MetricsRegistry", "NullTracer", "Telemetry",
-    "Tracer", "aggregate_counter_fields", "note_runtime_event",
-    "runtime_events", "tenant_tid", "validate_events", "validate_trace_file",
+    "Tracer", "aggregate_counter_fields", "tenant_tid", "validate_events",
+    "validate_trace_file",
 ]

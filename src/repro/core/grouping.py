@@ -59,6 +59,7 @@ from .cost_models import DeviceFleet
 from .jdob import (BatchedPlanner, Schedule, fused_scan_viable,
                    jdob_schedule, og_plan_fused)
 from .planner_service import PlannerService
+from .telemetry import span
 from .timeline import GpuTimeline, TimelineCursor
 
 #: grouping-DP execution backends: "dispatch" folds the DP host-side with
@@ -111,18 +112,20 @@ def _run_dp(M: int, cursor: TimelineCursor, solve, level_prefetch=None,
         dp = [(0.0, cursor, -1)]
     start = len(dp)
     for j in range(start, M + 1):
-        if level_prefetch is not None:
-            level_prefetch(j, dp)
-        best = (INF, cursor, 0)
-        for i in range(j):
-            e_i, cur_i, _ = dp[i]
-            if not np.isfinite(e_i):
-                continue
-            s = solve(i, j, cur_i.t_free)
-            cand = e_i + s.energy
-            if cand < best[0]:
-                best = (cand, cur_i.advance(s), i)
-        dp.append(best)
+        with span("repro.og.level", level=j):
+            if level_prefetch is not None:
+                level_prefetch(j, dp)
+            with span("repro.og.fold"):
+                best = (INF, cursor, 0)
+                for i in range(j):
+                    e_i, cur_i, _ = dp[i]
+                    if not np.isfinite(e_i):
+                        continue
+                    s = solve(i, j, cur_i.t_free)
+                    cand = e_i + s.energy
+                    if cand < best[0]:
+                        best = (cand, cur_i.advance(s), i)
+                dp.append(best)
     chain: list[tuple[int, int]] = []
     j = M
     while j > 0:
@@ -261,51 +264,59 @@ def _run_dp_pareto(M: int, cursor: TimelineCursor, solve,
             beam_hist.append((beam_width.width, beam_width.widenings))
     start = len(dp)
     for j in range(start, M + 1):
-        if level_prefetch is not None:
-            level_prefetch(j, dp)
-        cands = []
-        for i in range(j):
-            for si, st in enumerate(dp[i]):
-                e_i, cur_i = st[0], st[1]
-                if not np.isfinite(e_i):
-                    continue
-                s = solve(i, j, cur_i.t_free)
-                cands.append((e_i + s.energy, cur_i.advance(s), i, si))
-        a_best = None
-        if adaptive:
-            # re-fold _run_dp over the anchor chain (solves already memoized)
-            for i in range(j):
-                e_i, cur_i = dp[i][anchor[i]][0], dp[i][anchor[i]][1]
-                if not np.isfinite(e_i):
-                    continue
-                s = solve(i, j, cur_i.t_free)
-                cand = e_i + s.energy
-                if a_best is None or cand < a_best[0]:
-                    a_best = (cand, cur_i.advance(s), i, anchor[i])
-        front = _pareto_sweep(cands, frontier_eps, beam_width, stats)
-        if not front:
-            front = [(np.inf, cursor, 0, 0)]
-            if adaptive:
-                anchor.append(0)
-        elif adaptive:
-            if a_best is None:
-                anchor.append(0)
-            else:
-                ai = next((k for k, c in enumerate(front)
-                           if c[2] == a_best[2] and c[3] == a_best[3]), None)
-                if ai is None:
-                    front.append(a_best)
-                    front.sort(key=lambda c: (c[0], c[1].t_free, c[2], c[3]))
-                    ai = next(k for k, c in enumerate(front)
-                              if c[2] == a_best[2] and c[3] == a_best[3])
-                    if stats is not None:
-                        stats.frontier_states += 1
-                        stats.frontier_max = max(stats.frontier_max,
-                                                 len(front))
-                anchor.append(ai)
-        dp.append(front)
-        if adaptive and beam_hist is not None:
-            beam_hist.append((beam_width.width, beam_width.widenings))
+        with span("repro.og.level", level=j):
+            if level_prefetch is not None:
+                level_prefetch(j, dp)
+            with span("repro.og.fold"):
+                cands = []
+                for i in range(j):
+                    for si, st in enumerate(dp[i]):
+                        e_i, cur_i = st[0], st[1]
+                        if not np.isfinite(e_i):
+                            continue
+                        s = solve(i, j, cur_i.t_free)
+                        cands.append((e_i + s.energy, cur_i.advance(s), i,
+                                      si))
+                a_best = None
+                if adaptive:
+                    # re-fold _run_dp over the anchor chain (solves
+                    # already memoized)
+                    for i in range(j):
+                        e_i, cur_i = dp[i][anchor[i]][0], dp[i][anchor[i]][1]
+                        if not np.isfinite(e_i):
+                            continue
+                        s = solve(i, j, cur_i.t_free)
+                        cand = e_i + s.energy
+                        if a_best is None or cand < a_best[0]:
+                            a_best = (cand, cur_i.advance(s), i, anchor[i])
+                front = _pareto_sweep(cands, frontier_eps, beam_width, stats)
+                if not front:
+                    front = [(np.inf, cursor, 0, 0)]
+                    if adaptive:
+                        anchor.append(0)
+                elif adaptive:
+                    if a_best is None:
+                        anchor.append(0)
+                    else:
+                        ai = next((k for k, c in enumerate(front)
+                                   if c[2] == a_best[2]
+                                   and c[3] == a_best[3]), None)
+                        if ai is None:
+                            front.append(a_best)
+                            front.sort(key=lambda c: (c[0], c[1].t_free,
+                                                      c[2], c[3]))
+                            ai = next(k for k, c in enumerate(front)
+                                      if c[2] == a_best[2]
+                                      and c[3] == a_best[3])
+                            if stats is not None:
+                                stats.frontier_states += 1
+                                stats.frontier_max = max(stats.frontier_max,
+                                                         len(front))
+                        anchor.append(ai)
+                dp.append(front)
+                if adaptive and beam_hist is not None:
+                    beam_hist.append((beam_width.width,
+                                      beam_width.widenings))
     chain: list[tuple[int, int]] = []
     j, si = M, 0
     while j > 0:
@@ -438,109 +449,113 @@ def optimal_grouping(profile, fleet: DeviceFleet, edge,
                 and planner.rho == rho), \
             "prebuilt planner configuration disagrees with inner/rho"
 
-    M = fleet.M
-    order = np.argsort(fleet.deadline, kind="stable")
-    sorted_fleet = fleet.subset(order)
+    with span("repro.og.plan", users=fleet.M):
+        M = fleet.M
+        order = np.argsort(fleet.deadline, kind="stable")
+        sorted_fleet = fleet.subset(order)
 
-    # lazy segment construction: the dispatch DP touches all O(M²)
-    # contiguous segments of the sorted fleet, the fused path only the
-    # winning chain's
-    sub: dict[tuple[int, int], DeviceFleet] = {}
+        # lazy segment construction: the dispatch DP touches all O(M²)
+        # contiguous segments of the sorted fleet, the fused path only the
+        # winning chain's
+        sub: dict[tuple[int, int], DeviceFleet] = {}
 
-    def seg(i: int, j: int) -> DeviceFleet:
-        if (i, j) not in sub:
-            sub[(i, j)] = sorted_fleet.subset(np.arange(i, j))
-        return sub[(i, j)]
+        def seg(i: int, j: int) -> DeviceFleet:
+            if (i, j) not in sub:
+                sub[(i, j)] = sorted_fleet.subset(np.arange(i, j))
+            return sub[(i, j)]
 
-    # per-length shape buckets: each segment solves at the smallest of 2-3
-    # power-of-two user widths covering it, so a level's dispatches stop
-    # paying for masked users of short segments (the seed padded everything
-    # to the fleet-wide bucket, which sank the large-M speedup).  Padding
-    # is bit-invariant, so bucketing can never change results.
-    buckets = service.level_buckets(M)
-    # cache keyed exactly like the sequential DP's memo: (i, j, round(tf, 9))
-    cache: dict[tuple[int, int, float], Schedule] = {}
+        # per-length shape buckets: each segment solves at the smallest of 2-3
+        # power-of-two user widths covering it, so a level's dispatches stop
+        # paying for masked users of short segments (the seed padded everything
+        # to the fleet-wide bucket, which sank the large-M speedup).  Padding
+        # is bit-invariant, so bucketing can never change results.
+        buckets = service.level_buckets(M)
+        # cache keyed exactly like the sequential DP's memo:
+        # (i, j, round(tf, 9))
+        cache: dict[tuple[int, int, float], Schedule] = {}
 
-    def solve_many(pairs: Sequence[tuple[int, int, float]]):
-        by_bucket: dict[int, list[tuple[int, int, float]]] = {}
-        for (i, j, tf) in pairs:
-            by_bucket.setdefault(
-                service.bucket_for(j - i, buckets), []).append((i, j, tf))
-        # dispatch every bucket before materializing any: the device works
-        # on bucket k+1 while bucket k's winners transfer/reconstruct
-        pending = []
-        for b, part in sorted(by_bucket.items()):
-            pending.append((part, planner.plan_async(
-                [seg(i, j) for (i, j, _) in part],
-                [tf for (_, _, tf) in part], m_pad=b,
-                g_pad=service.level_group_pad(buckets, len(part)))))
-        for part, plans in pending:
-            for (i, j, tf), p in zip(part, plans.get()):
-                cache[(i, j, round(tf, 9))] = p
+        def solve_many(pairs: Sequence[tuple[int, int, float]]):
+            with span("repro.og.segments"):
+                by_bucket: dict[int, list[tuple[int, int, float]]] = {}
+                for (i, j, tf) in pairs:
+                    by_bucket.setdefault(service.bucket_for(j - i, buckets),
+                                         []).append((i, j, tf))
+                work = [(b, part, [seg(i, j) for (i, j, _) in part])
+                        for b, part in sorted(by_bucket.items())]
+            # dispatch every bucket before materializing any: the device
+            # works on bucket k+1 while bucket k's winners
+            # transfer/reconstruct
+            pending = [(part, planner.plan_async(
+                segs, [tf for (_, _, tf) in part], m_pad=b,
+                g_pad=service.level_group_pad(buckets, len(part))))
+                for b, part, segs in work]
+            for part, plans in pending:
+                for (i, j, tf), p in zip(part, plans.get()):
+                    cache[(i, j, round(tf, 9))] = p
 
-    def solve(i: int, j: int, tf: float) -> Schedule:
-        key = (i, j, round(tf, 9))
-        if key not in cache:
-            solve_many([(i, j, tf)])
-        return cache[key]
+        def solve(i: int, j: int, tf: float) -> Schedule:
+            key = (i, j, round(tf, 9))
+            if key not in cache:
+                solve_many([(i, j, tf)])
+            return cache[key]
 
-    def finish(chain) -> GroupedSchedule:
-        out = _collect_chain(chain, order, solve, TimelineCursor(t_free),
-                             timeline)
-        if _count_plan:
-            planner.stats.og_plans += 1
-            planner.stats.og_dispatches += planner.stats.dispatches - d0
-        return out
+        def finish(chain) -> GroupedSchedule:
+            out = _collect_chain(chain, order, solve, TimelineCursor(t_free),
+                                 timeline)
+            if _count_plan:
+                planner.stats.og_plans += 1
+                planner.stats.og_dispatches += planner.stats.dispatches - d0
+            return out
 
-    d0 = planner.stats.dispatches
-    if dp_backend == "fused":
-        if not fused_scan_viable(M):
-            # size crossover: past it the scan's fixed-shape work loses
-            # more compute than one-dispatch folding saves — route to the
-            # dispatch fold (a policy decision, counted, not a failure)
-            planner.stats.fused_routed += 1
-        else:
-            res = og_plan_fused(planner, sorted_fleet, t_free=t_free,
-                                mode=dp, frontier_eps=frontier_eps,
-                                beam_width=_resolve_beam(beam_width),
-                                stats=planner.stats)
-            if res.overflow:
-                planner.stats.fused_fallbacks += 1
+        d0 = planner.stats.dispatches
+        if dp_backend == "fused":
+            if not fused_scan_viable(M):
+                # size crossover: past it the scan's fixed-shape work loses
+                # more compute than one-dispatch folding saves — route to the
+                # dispatch fold (a policy decision, counted, not a failure)
+                planner.stats.fused_routed += 1
             else:
-                return finish(_fused_chain(
-                    [[(0.0, t_free, -1, 0)]] + res.rows, M))
+                res = og_plan_fused(planner, sorted_fleet, t_free=t_free,
+                                    mode=dp, frontier_eps=frontier_eps,
+                                    beam_width=_resolve_beam(beam_width),
+                                    stats=planner.stats)
+                if res.overflow:
+                    planner.stats.fused_fallbacks += 1
+                else:
+                    return finish(_fused_chain(
+                        [[(0.0, t_free, -1, 0)]] + res.rows, M))
 
-    # dispatch backend (and the fused overflow fallback): overlap XLA
-    # compiles with the DP's early levels by background-compiling every
-    # shape this fleet can need, in first-need order
-    for b, g in service.level_shapes(M):
-        planner.prefetch(b, g)
+        # dispatch backend (and the fused overflow fallback): overlap XLA
+        # compiles with the DP's early levels by background-compiling every
+        # shape this fleet can need, in first-need order
+        for b, g in service.level_shapes(M):
+            planner.prefetch(b, g)
 
-    def level_prefetch(j: int, states) -> None:
-        # level-synchronous batching: when level j folds, dp[0..j-1] are
-        # final, so the threaded t_free of every candidate (i, state, j)
-        # is known — warm all of the level's missing solves in ONE
-        # batched dispatch (the pareto DP's frontier states of one level
-        # can share a rounded t_free, hence the seen-set dedup)
-        need, seen = [], set()
-        for i in range(j):
-            for st in _entry_states(states[i]):
-                key = (i, j, round(st[1].t_free, 9))
-                if np.isfinite(st[0]) and key not in cache \
-                        and key not in seen:
-                    seen.add(key)
-                    need.append((i, j, st[1].t_free))
-        if need:
-            solve_many(need)
+        def level_prefetch(j: int, states) -> None:
+            # level-synchronous batching: when level j folds, dp[0..j-1] are
+            # final, so the threaded t_free of every candidate (i, state, j)
+            # is known — warm all of the level's missing solves in ONE
+            # batched dispatch (the pareto DP's frontier states of one level
+            # can share a rounded t_free, hence the seen-set dedup)
+            need, seen = [], set()
+            for i in range(j):
+                for st in _entry_states(states[i]):
+                    key = (i, j, round(st[1].t_free, 9))
+                    if np.isfinite(st[0]) and key not in cache \
+                            and key not in seen:
+                        seen.add(key)
+                        need.append((i, j, st[1].t_free))
+            if need:
+                solve_many(need)
 
-    if dp == "pareto":
-        chain = _run_dp_pareto(M, TimelineCursor(t_free), solve,
-                               level_prefetch, frontier_eps=frontier_eps,
-                               beam_width=_resolve_beam(beam_width),
-                               stats=planner.stats)
-    else:
-        chain = _run_dp(M, TimelineCursor(t_free), solve, level_prefetch)
-    return finish(chain)
+        if dp == "pareto":
+            chain = _run_dp_pareto(M, TimelineCursor(t_free), solve,
+                                   level_prefetch, frontier_eps=frontier_eps,
+                                   beam_width=_resolve_beam(beam_width),
+                                   stats=planner.stats)
+        else:
+            chain = _run_dp(M, TimelineCursor(t_free), solve, level_prefetch)
+        return finish(chain)
 
 
 class IncrementalOgState:

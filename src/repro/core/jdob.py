@@ -47,6 +47,7 @@ import numpy as np
 
 from .cost_models import DeviceFleet, EdgeProfile
 from .task_model import TaskProfile
+from .telemetry import span
 
 _GHZ = 1e9
 _INF = jnp.inf
@@ -570,8 +571,9 @@ class ExecutableCache:
 
     @staticmethod
     def _compile(args, n_partitions: int, sort_key: str):
-        return jdob_plan_batched.lower(
-            *args, n_partitions=n_partitions, sort_key=sort_key).compile()
+        with span("repro.plan.compile"):
+            return jdob_plan_batched.lower(
+                *args, n_partitions=n_partitions, sort_key=sort_key).compile()
 
     def _install(self, key, exe, stats: PlannerStats | None):
         """Insert under lock; LRU-evict past the bound."""
@@ -807,18 +809,21 @@ class BatchedPlanner:
         return chunks
 
     def _materialize(self, fleets, t_frees, chunks) -> list[Schedule]:
-        schedules: list[Schedule] = []
-        for s, n_real, outs in chunks:
+        with span("repro.plan.fetch"):
             # ONE device→host transfer per output array, not one tiny
             # jnp slice per group: per-group indexing of jnp arrays was
             # ~90% of warm planning time at M = 80 ("E" stays on device —
             # reconstruction never reads the full grid)
-            outs = [{k: np.asarray(v) for k, v in o.items() if k != "E"}
-                    for o in outs]
-            self.stats.groups_planned += n_real
-            for g in range(n_real):
-                schedules.append(self._reconstruct(
-                    fleets[s + g], float(t_frees[s + g]), outs, g))
+            host = [(s, n_real, [{k: np.asarray(v) for k, v in o.items()
+                                  if k != "E"} for o in outs])
+                    for s, n_real, outs in chunks]
+        schedules: list[Schedule] = []
+        with span("repro.plan.reconstruct"):
+            for s, n_real, outs in host:
+                self.stats.groups_planned += n_real
+                for g in range(n_real):
+                    schedules.append(self._reconstruct(
+                        fleets[s + g], float(t_frees[s + g]), outs, g))
         return schedules
 
     def plan(self, fleets: Sequence[DeviceFleet],
@@ -856,7 +861,8 @@ class BatchedPlanner:
         # compiles happen inside _dispatch (executable-cache misses): the
         # miss delta classifies this sample as cold-compile vs steady-state
         m0 = self.stats.misses
-        chunks = self._dispatch(fleets, t_frees, pad_users, m_pad, g_pad)
+        with span("repro.plan.dispatch"):
+            chunks = self._dispatch(fleets, t_frees, pad_users, m_pad, g_pad)
         return PendingPlans(self, list(fleets), list(t_frees), chunks, t0,
                             compiled=self.stats.misses > m0)
 

@@ -71,7 +71,7 @@ from .jdob import BatchedPlanner, Schedule
 from .planner_service import PlannerService, planner_spec
 from .task_model import TaskProfile
 from .telemetry import (NULL_TRACER, TID_GPU, TID_PLANNER, TID_UPLINK,
-                        Telemetry, tenant_tid)
+                        Telemetry, span, tenant_tid)
 from .timeline import (OCCUPANCY_MODES, GpuTimeline, rescale_edge_dvfs,
                        respeed_edge_dvfs)
 
@@ -855,27 +855,46 @@ class OnlineScheduler:
                 self.on_gpu_free(ev)
 
     def _flush(self, now: float) -> FlushEvent:
-        self.now = now
-        q, self._queue = self._queue, []
-        idx = np.array([a.user for a in q])
-        rel = np.array([a.abs_deadline - now for a in q])
-        late = int(np.sum(rel < self._l_min[idx] - 1e-12))
-        self.violations += late
-        sub = dataclasses.replace(self.fleet.subset(idx), deadline=rel)
-        self._flush_upload = None
-        self._flush_session = None
-        self._flush_rates = None
-        if (self.channel is not None and not self.channel.static
-                and self.channel_aware):
-            # plan against the channel's contended-rate snapshot: the
-            # batch's members plus every upload already in flight assumed
-            # concurrent (the jitted grid is unchanged — rates were
-            # already a per-user input array)
-            eff = self.channel.effective_rates(
-                sub.rate, now, keys=[(self.tenant_id, int(u)) for u in idx])
-            sub = dataclasses.replace(sub, rate=eff)
-            self._flush_rates = eff
-        s = self._plan_slot(now, sub, q)
+        with span("repro.loop.flush", flush=len(self._batches),
+                  batch=len(self._queue)):
+            self.now = now
+            q, self._queue = self._queue, []
+            idx = np.array([a.user for a in q])
+            rel = np.array([a.abs_deadline - now for a in q])
+            late = int(np.sum(rel < self._l_min[idx] - 1e-12))
+            self.violations += late
+            sub = dataclasses.replace(self.fleet.subset(idx), deadline=rel)
+            self._flush_upload = None
+            self._flush_session = None
+            self._flush_rates = None
+            if (self.channel is not None and not self.channel.static
+                    and self.channel_aware):
+                # plan against the channel's contended-rate snapshot: the
+                # batch's members plus every upload already in flight
+                # assumed concurrent (the jitted grid is unchanged — rates
+                # were already a per-user input array)
+                eff = self.channel.effective_rates(
+                    sub.rate, now,
+                    keys=[(self.tenant_id, int(u)) for u in idx])
+                sub = dataclasses.replace(sub, rate=eff)
+                self._flush_rates = eff
+            s = self._plan_slot(now, sub, q)
+            with span("repro.loop.book"):
+                ev, s, gpu_free = self._book_flush(now, q, idx, sub, s, late)
+            if self.on_flush is not None:
+                self.on_flush(ev)
+            if s.offload.any():
+                heapq.heappush(self._timers,
+                               (gpu_free, next(self._seq),
+                                GpuFreeEvent(gpu_free, ev)))
+            return ev
+
+    def _book_flush(self, now: float, q: list, idx: np.ndarray,
+                    sub: DeviceFleet, s: Schedule, late: int):
+        """Everything between a flush's plan and its ``on_flush`` hook: the
+        stagger re-plan, the post-plan rescale, channel actualization,
+        energy accounting, the booking and the :class:`FlushEvent`.
+        Returns the event, its final schedule and its GPU-free time."""
         sub, s = self._stagger_replan(now, q, idx, sub, s)
         s = self._post_plan(now, q, s)
         s = self._actualize(now, q, idx, sub, s)
@@ -904,13 +923,7 @@ class OnlineScheduler:
         if self._tr.enabled:
             self._trace_flush(now, q, sub, s, ev)
         self._after_flush(ev)
-        if self.on_flush is not None:
-            self.on_flush(ev)
-        if s.offload.any():
-            heapq.heappush(self._timers,
-                           (gpu_free, next(self._seq), GpuFreeEvent(gpu_free,
-                                                                    ev)))
-        return ev
+        return ev, s, gpu_free
 
     def _trace_flush(self, now: float, q: list, sub: DeviceFleet,
                      s: Schedule, ev: FlushEvent) -> None:
@@ -1171,20 +1184,22 @@ class OnlineScheduler:
         a :meth:`run_batched` drive is bit-identical to :meth:`run` —
         same flushes, same batches, same accounting — it just takes one
         pass per flush instead of one per event."""
-        t_policy = self._drain_arrivals(self.batch_window)
-        if t_policy is None:
-            self._fire_timers(np.inf)
-            return None
-        if self._planner is not None:
-            # warm the flush's batch shape on the background compile pool
-            # (no-op when cached) so a first-seen size overlaps its XLA
-            # compile with the timer/bookkeeping work below, and the next
-            # flush of this size class pays nothing
-            from .jdob import _bucket
-            self._planner.prefetch(
-                _bucket(len(self._queue), self._planner.min_user_bucket), 1)
-        t_fire = max(t_policy, self._queue[-1].arrival)
-        self._fire_timers(t_fire)
+        with span("repro.loop.drain"):
+            t_policy = self._drain_arrivals(self.batch_window)
+            if t_policy is None:
+                self._fire_timers(np.inf)
+                return None
+            if self._planner is not None:
+                # warm the flush's batch shape on the background compile
+                # pool (no-op when cached) so a first-seen size overlaps
+                # its XLA compile with the timer/bookkeeping work below,
+                # and the next flush of this size class pays nothing
+                from .jdob import _bucket
+                self._planner.prefetch(
+                    _bucket(len(self._queue), self._planner.min_user_bucket),
+                    1)
+            t_fire = max(t_policy, self._queue[-1].arrival)
+            self._fire_timers(t_fire)
         return self._flush(t_fire)
 
     def run_batched(self) -> OnlineResult:

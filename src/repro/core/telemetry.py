@@ -14,6 +14,11 @@ module is the single observability substrate they thread through:
 * :class:`Telemetry` — the bundle handed to schedulers, plus the
   per-request lifecycle log (arrival → flush → gpu_start → done, slack
   at completion, energy).
+* :func:`span` — wall-clock program spans on the **profiler's clock**
+  (``jax.profiler.TraceAnnotation``), at the layer boundaries of the
+  main path; every name is in :data:`WALL_SPANS`.  Capture them with
+  ``jax.profiler.trace(dir)`` around a run: they sit on the host thread
+  beside the device ops (Perfetto or TensorBoard).
 
 Determinism contract
 --------------------
@@ -33,20 +38,29 @@ one attribute load per site and allocates nothing.  Results must be
 bit-identical with tracing on vs off — emission sites are read-only
 observers and never perturb float math or control flow
 (tests/core/test_telemetry.py pins both properties).
+
+Wall-clock spans
+----------------
+:func:`span` never enters the simulated :class:`Tracer` and never changes
+a result or the control flow.  Under a profiler it is a
+``jax.profiler.TraceAnnotation``; with none running, one shared no-op
+context (a few hundred ns a site).  Sites sit at flush, plan-call and
+DP-level granularity only, never per arrival, per user or per layer
+step, so the off cost stays far below a flush.  There is no switch: the
+profiler being on is the switch.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
-import threading
 from typing import Any, Iterable, Sequence
 
 __all__ = [
     "NULL_TRACER", "NullTracer", "Tracer", "MetricsRegistry", "Telemetry",
     "PID_SIM", "TID_RUN", "TID_GPU", "TID_UPLINK", "TID_PLANNER",
     "tenant_tid", "validate_events", "validate_trace_file",
-    "aggregate_counter_fields", "note_runtime_event", "runtime_events",
-    "reset_runtime_events",
+    "aggregate_counter_fields", "WALL_SPANS", "span",
 ]
 
 # ---------------------------------------------------------------------------
@@ -353,9 +367,6 @@ class Telemetry:
         doc: dict[str, Any] = {"sim_time": self.metrics.as_dict()}
         if self.request_log:
             doc["requests"] = self.requests
-        ev = runtime_events()
-        if ev:
-            doc["runtime_events"] = ev
         if planner_stats is not None:
             doc["planner"] = planner_stats.as_dict()
             if planner_stats.frontier_levels:
@@ -395,31 +406,74 @@ def aggregate_counter_fields(cls, objs: Iterable[Any],
 
 
 # ---------------------------------------------------------------------------
-# process-wide runtime events
+# wall-clock program spans (the profiler's clock)
 # ---------------------------------------------------------------------------
-_RUNTIME_EVENTS: dict[str, dict] = {}
-_RUNTIME_LOCK = threading.Lock()
+_LOOP, _PLAN, _OG, _EXEC = ("event loop", "planner service", "grouping DP",
+                            "executor")
+_IN_FLUSH = ("repro.loop.flush",)
+#: where a planner call runs: a flush's plan, a stagger or channel re-plan
+#: during booking, a grouping-DP level (or, on the reference DP, its fold)
+_PLAN_PARENTS = ("repro.loop.flush", "repro.loop.book", "repro.og.level",
+                 "repro.og.fold")
+
+#: every program span: name -> (layer, parent spans, what it covers).  A
+#: span runs inside one of its parents, or outside any span when its layer
+#: is entered directly (a planner warm-up, ``run_partitioned`` called
+#: alone, a compile on the cache's background thread)
+WALL_SPANS: dict[str, tuple[str, tuple[str, ...], str]] = {
+    "repro.loop.drain": (_LOOP, (), "one step_batch's arrival drain, the "
+                         "timers it fires and the flush shape's prefetch"),
+    "repro.loop.flush": (_LOOP, (), "one flush, from its queue to its "
+                         "on_flush hook (flush=seq, batch=requests)"),
+    "repro.loop.book": (_LOOP, _IN_FLUSH, "everything after the flush's plan "
+                        "up to on_flush: stagger, post-plan, actualize, "
+                        "energy accounting, booking, the FlushEvent"),
+    "repro.plan.dispatch": (_PLAN, _PLAN_PARENTS, "plan_async: padding, "
+                            "executable lookup, device launch"),
+    "repro.plan.fetch": (_PLAN, _PLAN_PARENTS, "the device-to-host copy of "
+                         "a plan call's outputs, waiting on the device"),
+    "repro.plan.reconstruct": (_PLAN, _PLAN_PARENTS, "host reconstruction "
+                               "of each group's schedule"),
+    "repro.plan.compile": (_PLAN, ("repro.plan.dispatch",), "a planner "
+                           "executable compile, on whatever thread it runs"),
+    "repro.og.plan": (_OG, (), "one optimal grouping (users=M)"),
+    "repro.og.level": (_OG, ("repro.og.plan",), "one DP level (level=j): "
+                       "its solves and its fold"),
+    "repro.og.segments": (_OG, ("repro.og.level",), "the level's solve list "
+                          "and the sub-fleets of its segments"),
+    "repro.og.fold": (_OG, ("repro.og.level",), "the host fold of the "
+                      "level's solved segments into DP states"),
+    "repro.exec.prepare": (_EXEC, _IN_FLUSH, "token stack, embedding "
+                           "dispatch, the output allocation"),
+    "repro.exec.split": (_EXEC, _IN_FLUSH, "the row gathers of one side "
+                         "(local or offloaded)"),
+    "repro.exec.blocks": (_EXEC, _IN_FLUSH, "the layer-step dispatches of "
+                          "one pass (lo=, hi=)"),
+    "repro.exec.head": (_EXEC, _IN_FLUSH, "the head-step dispatch"),
+    "repro.exec.wait": (_EXEC, _IN_FLUSH, "block_until_ready on the head "
+                        "output: device work still outstanding"),
+    "repro.exec.to_host": (_EXEC, _IN_FLUSH, "the logits copy to the host "
+                           "(bytes=)"),
+    "repro.exec.scatter": (_EXEC, _IN_FLUSH, "the logits rows written into "
+                           "the output"),
+}
 
 
-def note_runtime_event(key: str, message: str,
-                       category: str = "runtime-warning") -> None:
-    """Record a process-wide runtime event (idempotent key, counted).
-
-    Used by paths that cannot reach a per-run :class:`Telemetry`
-    instance, so what they report shows up in run metrics instead of
-    only on stderr."""
-    with _RUNTIME_LOCK:
-        ev = _RUNTIME_EVENTS.setdefault(
-            key, {"count": 0, "message": message, "category": category})
-        ev["count"] += 1
+#: the shared no-op span while no profiler runs
+_NO_SPAN = contextlib.nullcontext()
+_TraceAnnotation = None
 
 
-def runtime_events() -> dict[str, dict]:
-    """Snapshot of process-wide runtime events (key → count/message)."""
-    with _RUNTIME_LOCK:
-        return {k: dict(v) for k, v in sorted(_RUNTIME_EVENTS.items())}
-
-
-def reset_runtime_events() -> None:
-    with _RUNTIME_LOCK:
-        _RUNTIME_EVENTS.clear()
+def span(name: str, **args):
+    """A wall-clock program span: ``with span("repro.exec.wait"): ...``.
+    ``name`` is a key of :data:`WALL_SPANS`; ``args`` (ints, floats,
+    strings) tie spans together (``flush=``, ``level=``, ``bytes=``) and
+    land as event stats in the profiler's trace.  With no profiler
+    running the span is one shared no-op."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        # jax imported on first use: the simulator's users pay nothing
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    if not _TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return _TraceAnnotation(name, **args)

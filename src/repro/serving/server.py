@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -38,6 +39,7 @@ from repro.core import (ChannelModel, DeviceFleet, EdgeProfile, FlushEvent,
                         OnlineArrival, OnlineResult, OnlineScheduler,
                         PlannerService, Schedule, TaskProfile, Telemetry,
                         Tenant, jdob_plus, jdob_schedule)
+from repro.core.telemetry import span
 from .engine import BlockwiseExecutor
 
 
@@ -101,33 +103,53 @@ def run_partitioned(executor: BlockwiseExecutor, vocab_size: int,
     folded into block 1, LM head into block N — matching
     ``core.task_model.profile_from_arch``)."""
     ex = executor
-    tokens = jnp.asarray(np.stack([r.tokens for r in requests]))
-    vision = None
-    if requests[0].vision is not None:
-        vision = jnp.asarray(np.stack([r.vision for r in requests]))
+    with span("repro.exec.prepare"):
+        tokens = jnp.asarray(np.stack([r.tokens for r in requests]))
+        vision = None
+        if requests[0].vision is not None:
+            vision = jnp.asarray(np.stack([r.vision for r in requests]))
+        h = ex.embed(tokens)
+        out = np.zeros((len(requests),) + h.shape[1:-1] + (vocab_size,),
+                       np.float32)
     n_layers = len(ex.layers)
     nt = sched.partition
-    h = ex.embed(tokens)
-    out = np.zeros((len(requests),) + h.shape[1:-1] + (vocab_size,),
-                   np.float32)
-
     off = sched.offload
     loc = ~off
     if loc.any():
-        hl = ex.run_blocks(h[loc], 0, n_layers,
-                           vision=None if vision is None else vision[loc])
-        out[np.where(loc)[0]] = np.asarray(ex.head(hl))
+        with span("repro.exec.split"):
+            hl = h[loc]
+            vl = None if vision is None else vision[loc]
+        with span("repro.exec.blocks", lo=0, hi=n_layers):
+            hl = ex.run_blocks(hl, 0, n_layers, vision=vl)
+        _head_to(out, loc, ex, hl)
     if off.any():
+        with span("repro.exec.split"):
+            ho = h[off]
+            vo = None if vision is None else vision[off]
         # device side: blocks 1..nt  (nt layers of the transformer, capped
         # at n_layers — block N is the head, edge-only here)
         dev_hi = min(nt, n_layers)
-        ho = ex.run_blocks(h[off], 0, dev_hi,
-                           vision=None if vision is None else vision[off])
+        with span("repro.exec.blocks", lo=0, hi=dev_hi):
+            ho = ex.run_blocks(ho, 0, dev_hi, vision=vo)
         # "upload" boundary activation; edge batches the suffix
-        ho = ex.run_blocks(ho, dev_hi, n_layers,
-                           vision=None if vision is None else vision[off])
-        out[np.where(off)[0]] = np.asarray(ex.head(ho))
+        with span("repro.exec.blocks", lo=dev_hi, hi=n_layers):
+            ho = ex.run_blocks(ho, dev_hi, n_layers, vision=vo)
+        _head_to(out, off, ex, ho)
     return out
+
+
+def _head_to(out: np.ndarray, rows: np.ndarray, ex: BlockwiseExecutor,
+             h) -> None:
+    """The head on ``h``, its logits written into ``out[rows]``: device
+    wait, host copy and scatter each a span of their own."""
+    with span("repro.exec.head"):
+        logits = ex.head(h)
+    with span("repro.exec.wait"):
+        jax.block_until_ready(logits)
+    with span("repro.exec.to_host", bytes=logits.nbytes):
+        host = np.asarray(logits)
+    with span("repro.exec.scatter"):
+        out[np.where(rows)[0]] = host
 
 
 class CoInferenceServer:

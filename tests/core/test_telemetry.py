@@ -1,21 +1,26 @@
 """Telemetry subsystem: tracing never perturbs results (bit-identical on
-vs off), traces are schema-valid, causally sane and byte-stable, and the
-fields-metadata-driven counter aggregation round-trips every field."""
+vs off), traces are schema-valid, causally sane and byte-stable, the
+fields-metadata-driven counter aggregation round-trips every field, and
+the wall-clock program spans land in a profiler capture under their
+catalog parents."""
 import dataclasses
+import glob
 import json
+import re
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core import (MultiTenantScheduler, OnlineArrival, OnlineResult,
-                        OnlineScheduler, PlannerStats, Telemetry, Tenant,
+                        OnlineScheduler, PlannerService, PlannerStats,
+                        Schedule, Telemetry, Tenant,
                         aggregate_counter_fields, make_channel,
                         make_edge_profile, make_fleet, mobilenet_v2_profile,
-                        note_runtime_event, poisson_arrivals, runtime_events,
-                        validate_events)
-from repro.core.telemetry import (NULL_TRACER, TID_GPU, Histogram,
-                                  MetricsRegistry, Tracer,
-                                  reset_runtime_events, tenant_tid)
+                        poisson_arrivals, validate_events)
+from repro.core.telemetry import (NULL_TRACER, TID_GPU, WALL_SPANS,
+                                  Histogram, MetricsRegistry, Tracer, span,
+                                  tenant_tid)
 
 PROF = mobilenet_v2_profile()
 EDGE = make_edge_profile(PROF)
@@ -341,25 +346,6 @@ def test_multi_tenant_result_sums_per_scheduler_counters():
                                        for t in r.tenants), name
 
 
-# ---------------------------------------------------------------------------
-# satellite 6: runtime events registry
-# ---------------------------------------------------------------------------
-
-def test_runtime_events_registry_counts_and_snapshots():
-    reset_runtime_events()
-    try:
-        note_runtime_event("test.key", "something fell back")
-        note_runtime_event("test.key", "something fell back")
-        ev = runtime_events()
-        assert ev["test.key"]["count"] == 2
-        assert ev["test.key"]["category"] == "runtime-warning"
-        # snapshot is a copy: mutating it must not touch the registry
-        ev["test.key"]["count"] = 99
-        assert runtime_events()["test.key"]["count"] == 2
-    finally:
-        reset_runtime_events()
-
-
 def test_metrics_document_separates_wall_time(tmp_path):
     tel = Telemetry()
     sched, _ = _run_online(tel)
@@ -374,3 +360,139 @@ def test_metrics_document_separates_wall_time(tmp_path):
     tel.export_metrics(str(p), planner_stats=stats)
     back = json.loads(p.read_text())
     assert back["wall_time"]["note"].startswith("perf_counter_ns")
+
+
+# ---------------------------------------------------------------------------
+# wall-clock program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+SRC = Path(__file__).resolve().parents[2] / "src" / "repro"
+
+
+def _one_flush():
+    """One ``step_batch`` flush of a small online fleet."""
+    fleet = make_fleet(8, PROF, EDGE, beta=20.0, seed=0)
+    sched = OnlineScheduler(PROF, fleet, EDGE, policy="slack")
+    sched.submit_many(poisson_arrivals(8, 200.0, fleet, seed=0))
+    ev = sched.step_batch()
+    s = ev.schedule
+    return (ev.time, ev.users.tolist(), ev.gpu_free, s.energy, s.partition,
+            s.f_edge, s.offload.tolist(), s.f_device.tolist())
+
+
+def _plan_six():
+    """A ``plan_fleet`` of 6 users through the grouping DP."""
+    fleet = make_fleet(6, PROF, EDGE, beta=5.0, seed=3)
+    g = PlannerService(PROF, EDGE).plan_fleet(fleet)
+    return (g.energy, [x.tolist() for x in g.groups],
+            [(s.energy, s.partition, s.offload.tolist()) for s in g.schedules])
+
+
+def _partitioned():
+    """``run_partitioned`` on a 2-layer model, one local and two
+    offloaded users split after layer 1."""
+    import jax
+    from repro.configs import ARCHS
+    from repro.models import init_params
+    from repro.serving import BlockwiseExecutor, Request
+    from repro.serving.server import run_partitioned
+    cfg = ARCHS["glm4-9b"].reduced()
+    assert cfg.num_layers == 2
+    ex = BlockwiseExecutor(cfg, init_params(cfg, jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(0)
+    reqs = [Request(user=m, deadline=1.0,
+                    tokens=rng.integers(0, cfg.vocab_size, 8, dtype=np.int32))
+            for m in range(3)]
+    sched = Schedule(feasible=True, energy=0.0, partition=1, f_edge=1e9,
+                     offload=np.array([True, False, True]),
+                     f_device=np.ones(3), t_free_end=0.0, terms={},
+                     per_user_energy=np.zeros(3))
+    return run_partitioned(ex, cfg.vocab_size, reqs, sched)
+
+
+_LOOP = {"repro.loop.drain", "repro.loop.flush", "repro.loop.book"}
+_PLAN = {"repro.plan.dispatch", "repro.plan.fetch", "repro.plan.reconstruct"}
+_OG = {"repro.og.plan", "repro.og.level", "repro.og.segments",
+       "repro.og.fold"}
+_EXEC = {"repro.exec.prepare", "repro.exec.split", "repro.exec.blocks",
+         "repro.exec.head", "repro.exec.wait", "repro.exec.to_host",
+         "repro.exec.scatter"}
+PATHS = {"flush": (_one_flush, _LOOP | _PLAN),
+         "plan_fleet": (_plan_six, _OG | _PLAN),
+         "run_partitioned": (_partitioned, _EXEC)}
+
+
+def _profiled(fn, logdir):
+    """``fn()`` under a profiler capture: its result and the capture's
+    ``repro.*`` host events as ``(name, start, end, thread, args)``."""
+    import jax
+    with jax.profiler.trace(str(logdir)):
+        out = fn()
+    path, = glob.glob(str(logdir / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for k, line in enumerate(plane.lines):
+                events += [(ev.name, ev.start_ns, ev.end_ns, k,
+                            dict(ev.stats)) for ev in line.events
+                           if ev.name.startswith("repro.")]
+    return out, events
+
+
+def _parent(ev, events):
+    """The innermost ``repro.*`` event on ``ev``'s thread holding it."""
+    best = None
+    for other in events:
+        if (other is not ev and other[3] == ev[3] and other[1] <= ev[1]
+                and ev[2] <= other[2]
+                and (best is None or other[1] >= best[1])):
+            best = other
+    return None if best is None else best[0]
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_profiled_run_has_catalog_spans_and_same_results(path, tmp_path):
+    fn, expected = PATHS[path]
+    plain = fn()                          # also warms every shape
+    out, events = _profiled(fn, tmp_path)
+    if isinstance(plain, np.ndarray):
+        np.testing.assert_array_equal(out, plain)
+    else:
+        assert out == plain
+    names = {ev[0] for ev in events}
+    assert names <= set(WALL_SPANS), names - set(WALL_SPANS)
+    assert expected <= names, expected - names
+    for ev in events:
+        parent = _parent(ev, events)
+        assert parent is None or parent in WALL_SPANS[ev[0]][1], \
+            (ev[0], parent)
+    if path == "run_partitioned":
+        assert sum(ev[4]["bytes"] for ev in events
+                   if ev[0] == "repro.exec.to_host") == out.nbytes
+
+
+def test_wall_span_catalog_is_closed():
+    layers = {layer for layer, _, _ in WALL_SPANS.values()}
+    assert layers == {"event loop", "planner service", "grouping DP",
+                      "executor"}
+    for name, (_, parents, what) in WALL_SPANS.items():
+        assert name.startswith("repro.") and what
+        assert set(parents) <= set(WALL_SPANS), name
+
+
+def test_every_span_site_names_a_catalog_span():
+    """A span name the catalog lacks is a bug, on paths no test runs too."""
+    sites = set()
+    for f in SRC.rglob("*.py"):
+        sites |= set(re.findall(r'span\(\s*"(repro\.[\w.]+)"',
+                                f.read_text()))
+    assert sites and sites <= set(WALL_SPANS), sites - set(WALL_SPANS)
+    assert set(WALL_SPANS) <= sites, set(WALL_SPANS) - sites
+
+
+def test_span_without_profiler_is_a_shared_noop():
+    a, b = span("repro.loop.flush", flush=1), span("repro.og.fold")
+    assert a is b
+    with a:
+        pass
