@@ -444,7 +444,9 @@ WALL_SPANS: dict[str, tuple[str, tuple[str, ...], str]] = {
     "repro.og.fold": (_OG, ("repro.og.level",), "the host fold of the "
                       "level's solved segments into DP states"),
     "repro.exec.prepare": (_EXEC, _IN_FLUSH, "token stack, embedding "
-                           "dispatch, the output allocation"),
+                           "dispatch, the output taken from the executor's "
+                           "host pool (reused=1: an earlier output's "
+                           "memory, its pages already resident)"),
     "repro.exec.split": (_EXEC, _IN_FLUSH, "the row gathers of one side "
                          "(local or offloaded)"),
     "repro.exec.blocks": (_EXEC, _IN_FLUSH, "the layer-step dispatches of "

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, LayerSpec
 from repro.models import RunCtx
 from repro.models.model import _apply_elem, rms_norm
+from .outputs import HostOutputs
 
 
 def layer_refs(cfg: ArchConfig) -> list[tuple[LayerSpec, int, int, int]]:
@@ -73,6 +74,9 @@ class BlockwiseExecutor:
     cfg: ArchConfig
     params: Any
     ctx: RunCtx = None
+    #: host memory for ``run_partitioned``'s outputs
+    outputs: HostOutputs = dataclasses.field(default_factory=HostOutputs,
+                                             repr=False, compare=False)
 
     def __post_init__(self):
         self.ctx = self.ctx or RunCtx(self.cfg, compute_dtype=jnp.float32,

@@ -101,20 +101,29 @@ def run_partitioned(executor: BlockwiseExecutor, vocab_size: int,
     boundary activation, and the edge batches the suffix.  Block index
     mapping: J-DOB block n ∈ {1..N} is transformer layer n (embedding
     folded into block 1, LM head into block N — matching
-    ``core.task_model.profile_from_arch``)."""
+    ``core.task_model.profile_from_arch``).
+
+    The result lives in a buffer of ``executor.outputs``, handed out again
+    once nothing refers to this result or a view of it.  It is not zeroed:
+    the local and the offloaded rows together write every row."""
     ex = executor
-    with span("repro.exec.prepare"):
+    off = sched.offload
+    loc = ~off
+    if off.shape != (len(requests),):
+        raise ValueError(f"offload mask of shape {off.shape} for "
+                         f"{len(requests)} requests")
+    with span("repro.exec.prepare") as sp:
         tokens = jnp.asarray(np.stack([r.tokens for r in requests]))
         vision = None
         if requests[0].vision is not None:
             vision = jnp.asarray(np.stack([r.vision for r in requests]))
         h = ex.embed(tokens)
-        out = np.zeros((len(requests),) + h.shape[1:-1] + (vocab_size,),
-                       np.float32)
+        out, reused = ex.outputs.take(
+            (len(requests),) + h.shape[1:-1] + (vocab_size,), np.float32)
+        if sp is not None:                  # the no-op span enters as None
+            sp.set_metadata(reused=int(reused))
     n_layers = len(ex.layers)
     nt = sched.partition
-    off = sched.offload
-    loc = ~off
     if loc.any():
         with span("repro.exec.split"):
             hl = h[loc]

@@ -4,6 +4,7 @@ fields-metadata-driven counter aggregation round-trips every field, and
 the wall-clock program spans land in a profiler capture under their
 catalog parents."""
 import dataclasses
+import functools
 import glob
 import json
 import re
@@ -388,17 +389,25 @@ def _plan_six():
             [(s.energy, s.partition, s.offload.tolist()) for s in g.schedules])
 
 
-def _partitioned():
-    """``run_partitioned`` on a 2-layer model, one local and two
-    offloaded users split after layer 1."""
+@functools.cache
+def _executor():
+    """One 2-layer executor, so that repeated calls share its output pool."""
     import jax
     from repro.configs import ARCHS
     from repro.models import init_params
-    from repro.serving import BlockwiseExecutor, Request
-    from repro.serving.server import run_partitioned
+    from repro.serving import BlockwiseExecutor
     cfg = ARCHS["glm4-9b"].reduced()
     assert cfg.num_layers == 2
-    ex = BlockwiseExecutor(cfg, init_params(cfg, jax.random.PRNGKey(0)))
+    return BlockwiseExecutor(cfg, init_params(cfg, jax.random.PRNGKey(0)))
+
+
+def _partitioned():
+    """``run_partitioned`` on a 2-layer model, one local and two
+    offloaded users split after layer 1."""
+    from repro.serving import Request
+    from repro.serving.server import run_partitioned
+    ex = _executor()
+    cfg = ex.cfg
     rng = np.random.default_rng(0)
     reqs = [Request(user=m, deadline=1.0,
                     tokens=rng.integers(0, cfg.vocab_size, 8, dtype=np.int32))
@@ -470,6 +479,16 @@ def test_profiled_run_has_catalog_spans_and_same_results(path, tmp_path):
     if path == "run_partitioned":
         assert sum(ev[4]["bytes"] for ev in events
                    if ev[0] == "repro.exec.to_host") == out.nbytes
+        # ``plain`` still holds the first output, so this one is new memory
+        assert _reused(events) == [0]
+        del plain, out
+        out, events = _profiled(fn, tmp_path / "again")
+        assert _reused(events) == [1]
+
+
+def _reused(events):
+    return [ev[4]["reused"] for ev in events
+            if ev[0] == "repro.exec.prepare"]
 
 
 def test_wall_span_catalog_is_closed():
