@@ -25,6 +25,7 @@ from __future__ import annotations
 import numpy as np
 
 from bench import deploy, traffic
+from bench.reference import model_module
 from bench.reference import planner as ref
 
 #: flushes (waves) of a run whose plans are checked against the reference
@@ -40,9 +41,9 @@ def sample(n: int, k: int, seed: int, tag: int = 11) -> list:
 
 
 def online(drive_cfg: dict, mix: dict, seed: int, ans: dict,
-           control=None) -> dict:
+           control=None, root=None) -> dict:
     """Numbers of an online cell (served or not) from its ``answers()``."""
-    P = deploy.task_profile(drive_cfg)
+    P = deploy.task_profile(drive_cfg, root)
     E = deploy.edge_profile(P, drive_cfg["edge"])
     fl = deploy.fleet(P, E, drive_cfg["fleet"],
                       traffic.device_betas(mix, seed))
@@ -112,8 +113,8 @@ def _score(P, E, sub, tf, sweep, keys, plan):
 
 
 def waves(drive_cfg: dict, mix: dict, seed: int, ans: dict,
-          control=None) -> dict:
-    P = deploy.task_profile(drive_cfg)
+          control=None, root=None) -> dict:
+    P = deploy.task_profile(drive_cfg, root)
     E = deploy.edge_profile(P, drive_cfg["edge"])
     sweep = deploy.f_sweep(E, drive_cfg["planner"]["rho"])
     keys = tuple(drive_cfg["planner"]["waves"])
@@ -152,16 +153,18 @@ def waves(drive_cfg: dict, mix: dict, seed: int, ans: dict,
 
 
 def logits(model: dict, weights, kept: dict, tokens: dict,
-           mode: str = "highest", vocab_block: int = 32000) -> dict:
+           mode: str = "highest", vocab_block: int = 32000,
+           root=None) -> dict:
     """Over the kept requests: ``logit_err``, max |Δlogit| over the
     reference's max |logit|, and ``logit_rms``, the root-mean-square
     |Δlogit| over the reference's root-mean-square logit.  ``mode`` other
     than ``"highest"`` scores the reference forward in that arithmetic (the
-    control) in place of the kept logits."""
+    control) in place of the kept logits.  The reference is the module the
+    configuration's ``model`` names (``bench/reference``)."""
     if not kept:
         return {"logit_err": float("inf"), "logit_rms": float("inf")}
     import jax
-    from bench.reference import transformer as tf
+    tf = model_module(model, root)
     ids = sorted(kept)
     toks = np.stack([tokens[i] for i in ids])
     got = np.stack([kept[i] for i in ids])

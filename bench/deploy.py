@@ -13,6 +13,8 @@ import dataclasses
 
 import numpy as np
 
+from bench.reference import model_module
+
 
 @dataclasses.dataclass(frozen=True)
 class Profile:
@@ -97,33 +99,30 @@ def mobilenet_v2_profile(input_res: int = 224, act_bytes: int = 4) -> Profile:
     return Profile("mobilenet_v2", A, O, np.ones_like(A), np.ones_like(A))
 
 
-def dense_prefill_profile(model: dict, seq: int, act_bytes: int = 2) -> Profile:
-    """One block per decoder layer of a dense full-attention model at
-    prefill of ``seq`` tokens; the embedding folds into block 1 and the LM
-    head into block N (what ``profile_from_arch`` does)."""
-    d, H, KV = model["d_model"], model["num_heads"], model["num_kv_heads"]
-    hd = model["head_dim"]
-    qkv = 2.0 * seq * d * (H * hd + 2 * KV * hd)
-    out = 2.0 * seq * H * hd * d
-    attn = 2.0 * 2.0 * seq * (seq / 2.0) * H * hd
-    mlp = 2.0 * seq * d * model["d_ff"] * (3 if model["gated_mlp"] else 2)
-    L = model["num_layers"]
-    A = [0.0] + [qkv + out + attn + mlp] * L
+def decoder_prefill_profile(model: dict, seq: int, act_bytes: int = 2,
+                            root=None) -> Profile:
+    """One block per decoder layer at prefill of ``seq`` tokens, each
+    costing what the model's reference module states for that layer
+    (``block_flops``); the embedding folds into block 1 and the LM head into
+    block N (what ``profile_from_arch`` does)."""
+    mod = model_module(model, root)
+    L, d = model["num_layers"], model["d_model"]
+    A = [0.0] + [mod.block_flops(model, i, seq) for i in range(L)]
     O = [float(seq * 4)] + [float(seq * d * act_bytes)] * L
-    A[-1] += 2.0 * seq * d * model["vocab_size"]
+    A[-1] += mod.head_flops(model, 1, seq)
     O[-1] = float(seq * model["vocab_size"] * act_bytes)
     A, O = np.asarray(A), np.asarray(O)
     return Profile(f"{model['arch']}:prefill@{seq}", A, O, np.ones_like(A),
                    np.ones_like(A))
 
 
-def task_profile(config: dict) -> Profile:
+def task_profile(config: dict, root=None) -> Profile:
     task = config["task"]
     if task["kind"] == "mobilenet_v2":
         return mobilenet_v2_profile(task["input_res"], task["act_bytes"])
     if task["kind"] == "dense_prefill":
-        return dense_prefill_profile(config["model"], task["seq"],
-                                     task["act_bytes"])
+        return decoder_prefill_profile(config["model"], task["seq"],
+                                       task["act_bytes"], root)
     raise ValueError(f"unknown task kind {task['kind']!r}")
 
 

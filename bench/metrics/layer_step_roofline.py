@@ -16,11 +16,11 @@ def read(run):
         return None
     least, by = 0.0, {"compute": 0.0, "memory": 0.0}
     for b in flops.layer_calls(f.sizes for f in run.flushes if f.traced):
-        t, bound = flops.roofline_s(flops.layer_flops(run.model, b, run.seq),
-                                    flops.layer_bytes(run.model, b, run.seq),
-                                    run.peak)
-        least += t * run.model["num_layers"]
-        by[bound] += t * run.model["num_layers"]
+        call = flops.layers_roofline_s(run.model, b, run.seq, run.peak,
+                                       run.root)
+        least += call["compute"] + call["memory"]
+        for bound, t in call.items():
+            by[bound] += t
     print(f"layer_step_roofline: least {least!r} s over device "
           f"{device_s!r} s; bound by compute {by['compute']!r} s, "
           f"memory {by['memory']!r} s", file=sys.stderr)

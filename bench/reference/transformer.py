@@ -8,6 +8,12 @@ MLP with tanh-approximated GELU (or SiLU-gated with three matrices);
 residual adds; final RMSNorm and an untied LM head.  Weights are read as
 stored and cast to float32; activations stay float32.
 
+The model is one segment of ``num_layers`` full-attention layers with a
+dense MLP each: a configuration that states another ``plan`` is refused.
+Beside the forward pass, this module gives the weight tree's shapes and the
+FLOP and byte counts of each layer and the head (the interface
+``bench/reference/__init__.py`` names).
+
 ``mode`` picks the matmul arithmetic: ``"highest"`` is float32 (the
 reference), ``"bf16x3"`` the three-pass bfloat16 product that TPUs call
 ``high`` (the control: hi·hi + hi·lo + lo·hi, spelled out so that it means
@@ -35,6 +41,12 @@ def check_equations(model: dict) -> None:
     """Raise unless the configuration states equations this reference
     implements (a configuration that asks for others must not be checked
     against these)."""
+    plan = model.get("plan")
+    if plan is not None and (len(plan) != 1 or any(
+            e.get("kind", "attn") != "attn" or e.get("ffn", "dense") != "dense"
+            or e.get("window") is not None for e in plan[0][0])):
+        raise ValueError(f"reference: plan={plan!r} is not implemented "
+                         "(only full-attention layers with a dense MLP)")
     for key, ok in EQUATIONS.items():
         if model[key] not in ok:
             raise ValueError(f"reference: {key}={model[key]!r} is not "
@@ -50,6 +62,81 @@ def check_equations(model: dict) -> None:
                          f"{model['matmul_precision']!r}")
 
 
+def shapes(model: dict) -> dict:
+    """Leaf shapes of the weight tree the executor reads: ``embed.w`` (V, d),
+    ``segments[0][0]`` holding every layer's arrays stacked on a leading
+    layer axis, ``final_norm`` (d,) and ``lm_head.w`` (d, V)."""
+    L, d, V = model["num_layers"], model["d_model"], model["vocab_size"]
+    H, KV, hd, ff = (model["num_heads"], model["num_kv_heads"],
+                     model["head_dim"], model["d_ff"])
+    layer = {"norm1": (L, d), "wq": (L, d, H * hd), "wk": (L, d, KV * hd),
+             "wv": (L, d, KV * hd), "wo": (L, H * hd, d),
+             "w_up": (L, d, ff), "w_down": (L, ff, d), "norm2": (L, d)}
+    if model["gated_mlp"]:
+        layer["w_gate"] = (L, d, ff)
+    return {"embed": {"w": (V, d)}, "segments": [[layer]],
+            "final_norm": (d,), "lm_head": {"w": (d, V)}}
+
+
+# ---- counts -----------------------------------------------------------------
+# A matmul of (m, k)·(k, n) is 2·m·k·n operations; attention's scores and
+# weighted sum are computed for the causal half of the (S, S) square
+# (diagonal included); elementwise work is not counted.  Bytes are the
+# weights read once in their stored type plus the float32 hidden states
+# read and written.  Every layer is alike, so ``i`` only names it.
+def layer_params(model: dict, i: int) -> int:
+    d, hd = model["d_model"], model["head_dim"]
+    attn = d * (model["num_heads"] + 2 * model["num_kv_heads"]) * hd \
+        + model["num_heads"] * hd * d
+    mlp = d * model["d_ff"] * (3 if model["gated_mlp"] else 2)
+    return attn + mlp + 2 * d
+
+
+def head_params(model: dict) -> int:
+    return model["d_model"] * model["vocab_size"] + model["d_model"]
+
+
+def _weight_bytes(model: dict) -> int:
+    return jnp.dtype(model["weight_dtype"]).itemsize
+
+
+def layer_flops(model: dict, i: int, batch: int, seq: int) -> float:
+    """Layer ``i`` over ``batch`` sequences of ``seq`` tokens."""
+    matmul = 2.0 * batch * seq * (layer_params(model, i)
+                                  - 2 * model["d_model"])
+    attn = 2.0 * 2.0 * batch * model["num_heads"] * model["head_dim"] \
+        * seq * (seq + 1) / 2.0
+    return matmul + attn
+
+
+def layer_bytes(model: dict, i: int, batch: int, seq: int) -> float:
+    w = layer_params(model, i) * _weight_bytes(model)
+    return w + 2.0 * batch * seq * model["d_model"] * 4
+
+
+def head_flops(model: dict, batch: int, seq: int) -> float:
+    return 2.0 * batch * seq * model["d_model"] * model["vocab_size"]
+
+
+def head_bytes(model: dict, batch: int, seq: int) -> float:
+    return (head_params(model) * _weight_bytes(model)
+            + batch * seq * (model["d_model"] + model["vocab_size"]) * 4)
+
+
+def block_flops(model: dict, i: int, seq: int) -> float:
+    """Per-sample FLOPs of layer ``i`` at prefill of ``seq`` tokens as the
+    task profile states them: projections, attention over half of the
+    (S, S) square, and the MLP (the program's ``profile_from_arch``)."""
+    d, H, KV = model["d_model"], model["num_heads"], model["num_kv_heads"]
+    hd = model["head_dim"]
+    qkv = 2.0 * seq * d * (H * hd + 2 * KV * hd)
+    out = 2.0 * seq * H * hd * d
+    attn = 2.0 * 2.0 * seq * (seq / 2.0) * H * hd
+    mlp = 2.0 * seq * d * model["d_ff"] * (3 if model["gated_mlp"] else 2)
+    return qkv + out + attn + mlp
+
+
+# ---- the forward pass -------------------------------------------------------
 def mm(spec: str, a, b, mode: str):
     if mode == "highest":
         return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST,
@@ -58,8 +145,10 @@ def mm(spec: str, a, b, mode: str):
         raise ValueError(f"unknown matmul mode {mode!r}")
 
     def split(x):
-        hi = x.astype(BF16)
-        return hi, (x - hi.astype(F32)).astype(BF16)
+        # rounded by reduce_precision, which compilers keep: a round trip
+        # through bfloat16 may be dropped as excess precision
+        hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+        return hi.astype(BF16), (x - hi).astype(BF16)
 
     (ah, al), (bh, bl) = split(a), split(b)
     dot = functools.partial(jnp.einsum, spec, preferred_element_type=F32)

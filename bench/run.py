@@ -154,7 +154,7 @@ def execute(spec: dict, name: str, seed: int, seconds: float, trace: bool,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     peak = peaks(devs[0].device_kind) if require_tpu else None
 
-    drv = sut.drive(config, mix, seed)
+    drv = sut.drive(config, mix, seed, root)
     drv.warm()
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
@@ -175,8 +175,8 @@ def execute(spec: dict, name: str, seed: int, seconds: float, trace: bool,
     drv.close()
     del drv
     gc.collect()
-    return dict(cell=cell, config=config, mix=mix, devs=devs, peak=peak,
-                setup_s=setup_s, flushes=flushes, wall=wall,
+    return dict(cell=cell, config=config, mix=mix, root=root, devs=devs,
+                peak=peak, setup_s=setup_s, flushes=flushes, wall=wall,
                 compiles=compiles, og_plans=og_plans, og_dispatches=og_disp,
                 summary=summary, served=served, answers=ans, params=params,
                 mem_peak=max(int(m.get("peak_bytes_in_use", 0)) for m in mem))
@@ -186,20 +186,24 @@ def compare(x: dict, seed: int, control=None) -> dict:
     """The numbers compared, each with its limit.  ``control`` (a numpy
     dtype) puts the reference planner in that precision, and the
     reference forward one step below the configuration's matmul precision
-    (three-pass bfloat16 for ``float32``), in the program's place."""
+    (three-pass bfloat16 for ``float32``), in the program's place.  The
+    reference forward is the module the configuration's ``model`` names."""
     from bench import check
-    config, mix, ans = x["config"], x["mix"], x["answers"]
+    from bench.reference import model_module
+    config, mix, ans, root = x["config"], x["mix"], x["answers"], x["root"]
     if mix["mode"] == "online":
-        nums = check.online(config, mix, seed, ans, control=control)
+        nums = check.online(config, mix, seed, ans, control=control,
+                            root=root)
     else:
-        nums = check.waves(config, mix, seed, ans, control=control)
+        nums = check.waves(config, mix, seed, ans, control=control,
+                           root=root)
     if x["served"]:
-        from bench.reference.transformer import CONTROL_BELOW
         model = config["model"]
+        below = model_module(model, root).CONTROL_BELOW
         nums.update(check.logits(
             model, x["params"], ans["kept"], ans["tokens"],
             "highest" if control is None
-            else CONTROL_BELOW[model["matmul_precision"]]))
+            else below[model["matmul_precision"]], root=root))
     limits = config["limits"]
     return {k: {"value": v, "limit": limits.get(k)} for k, v in nums.items()}
 
@@ -225,7 +229,7 @@ def result(spec: dict, x: dict, seed: int, trace: bool,
                           og_dispatches=x["og_dispatches"],
                           trace=x["summary"], model=x["config"].get("model"),
                           seq=x["mix"].get("prompt_tokens", 0),
-                          peak=x["peak"])
+                          peak=x["peak"], root=x["root"])
     metrics = {}
     for m in metrics_of(spec, x["cell"], trace):
         v = e2e[m["name"]] if not trace else reader(m["name"], root)(run)

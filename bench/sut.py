@@ -50,35 +50,50 @@ def program_fleet(fl: dict):
     return DeviceFleet(**fl)
 
 
-#: what the program's dense executor computes, by configuration key: a
+#: what the served executor computes, by configuration key, for a program
+#: that states no table of its own next to ``BlockwiseExecutor``: a
 #: configuration that states anything else cannot be served as stated
 PROGRAM = {"norm": ("rms",), "rotary_fraction": (1.0,),
            "compute_dtype": ("float32",), "matmul_precision": ("float32",)}
+#: the MLP activation it computes, by ``gated_mlp``: one name or a tuple
 PROGRAM_ACTIVATION = {False: "gelu_tanh", True: "silu"}
 
 
+def program_table():
+    """(``PROGRAM``, ``PROGRAM_ACTIVATION``) of the served executor: the
+    program's own tables in ``repro.serving.engine`` where it has them, else
+    this module's record of what it computes."""
+    import repro.serving.engine as engine
+    return (getattr(engine, "PROGRAM", PROGRAM),
+            getattr(engine, "PROGRAM_ACTIVATION", PROGRAM_ACTIVATION))
+
+
 def arch_config(model: dict):
-    """The program's ``ArchConfig`` of the configuration's model; raises
-    where the configuration states what the executor does not compute."""
-    from repro.configs.base import ArchConfig
-    for key, ok in PROGRAM.items():
+    """The program's ``ArchConfig`` of the configuration's model: every
+    ``ArchConfig`` field the ``model`` names, and its layer ``plan``, a list
+    of ``[[{"kind", "ffn", "window"}, ...], repeats]`` (none: one segment of
+    full-attention layers with a dense MLP).  Raises where the configuration
+    states what the executor does not compute."""
+    from repro.configs.base import ArchConfig, LayerSpec
+    computes, activation = program_table()
+    for key, ok in computes.items():
         if model[key] not in ok:
             raise ValueError(f"the served executor computes {key} in "
                              f"{ok}, not {model[key]!r}")
-    if model["mlp_activation"] != PROGRAM_ACTIVATION[bool(model["gated_mlp"])]:
+    acts = activation[bool(model["gated_mlp"])]
+    if model["mlp_activation"] not in (acts if isinstance(acts, tuple)
+                                       else (acts,)):
         raise ValueError(f"the served executor has no mlp_activation="
                          f"{model['mlp_activation']!r} with gated_mlp="
                          f"{model['gated_mlp']}")
-    return ArchConfig(name=model["arch"], family="dense",
-                      source="bench configuration",
-                      num_layers=model["num_layers"],
-                      d_model=model["d_model"], num_heads=model["num_heads"],
-                      num_kv_heads=model["num_kv_heads"],
-                      head_dim=model["head_dim"], d_ff=model["d_ff"],
-                      vocab_size=model["vocab_size"],
-                      gated_mlp=model["gated_mlp"],
-                      rope_theta=model["rope_theta"],
-                      norm_eps=model["norm_eps"])
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    kw = {k: v for k, v in model.items()
+          if k in fields - {"name", "source", "plan"}}
+    kw.setdefault("family", "dense")
+    if "plan" in model:
+        kw["plan"] = tuple((tuple(LayerSpec(**e) for e in pattern), reps)
+                           for pattern, reps in model["plan"])
+    return ArchConfig(name=model["arch"], source="bench configuration", **kw)
 
 
 @dataclasses.dataclass
@@ -139,10 +154,10 @@ class OnlineDrive:
 
     served = False
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    def __init__(self, config: dict, mix: dict, seed: int, root=None):
         from repro.core import PlannerService
-        self.config, self.mix, self.seed = config, mix, seed
-        self.P = deploy.task_profile(config)
+        self.config, self.mix, self.seed, self.root = config, mix, seed, root
+        self.P = deploy.task_profile(config, root)
         self.E = deploy.edge_profile(self.P, config["edge"])
         self.fl = deploy.fleet(self.P, self.E, config["fleet"],
                                traffic.device_betas(mix, seed))
@@ -294,14 +309,14 @@ class ServedDrive(OnlineDrive):
     served = True
     KEEP = 16
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    def __init__(self, config: dict, mix: dict, seed: int, root=None):
         self.model = config["model"]
-        super().__init__(config, mix, seed)
+        super().__init__(config, mix, seed, root)
 
     def _build(self) -> None:
         from repro.serving import CoInferenceServer
         from bench import weights
-        self.params = weights.make(self.model, self.seed)
+        self.params = weights.make(self.model, self.seed, self.root)
         self.server = CoInferenceServer(arch_config(self.model), self.params,
                                         self.profile, self.fleet, self.edge,
                                         inner=self.inner,
@@ -317,6 +332,29 @@ class ServedDrive(OnlineDrive):
 
     def _vocab(self) -> int:
         return self.model["vocab_size"]
+
+    #: arrivals replayed through the flush rule to size the warm-up: more
+    #: than a window serves
+    WARM_REPLAY = 8192
+
+    def max_batch(self) -> int:
+        """The planner's bound, raised to the largest flush the flush rule
+        makes of this seed's first ``WARM_REPLAY`` arrivals, and never more
+        than the fleet: every executor shape a flush of the window takes."""
+        from bench.reference.planner import replay_policy
+        top = super().max_batch()
+        if top >= self.mix["devices"]:
+            return top
+        self._times(self.WARM_REPLAY - 1)
+        times = np.concatenate([b.times for b in self.blocks])
+        devs = np.concatenate([b.devices for b in self.blocks])
+        s = self.config["scheduler"]
+        l_min = self.fl["zeta"] * self.P.v()[-1] / self.fl["f_max"]
+        flushes = replay_policy(times, self.T[devs], l_min[devs], s["policy"],
+                                s["keep_frac"], s.get("window", 0.0),
+                                len(times))
+        most = max(b - a for _, a, b, _ in flushes)
+        return min(self.mix["devices"], max(top, most))
 
     def _scheduler(self, on_flush=None):
         s = self.config["scheduler"]
@@ -358,20 +396,26 @@ class ServedDrive(OnlineDrive):
 
     def warm(self) -> None:
         """Compile every executor shape batches of 1..max can take (the
-        embedding, the row gathers that split a batch, the layer and head
-        steps), then the planner buckets and a few real flushes."""
+        embedding, the row gathers that split a batch, the layer step of
+        each element of the layer plan and the head step), then the planner
+        buckets and a few real flushes."""
         import jax
         import jax.numpy as jnp
         ex = self.server.executor
         S = self.mix["prompt_tokens"]
         top = self.max_batch()
+        firsts = {}                 # first layer of each (segment, element)
+        for i, (_, seg, elem, _) in enumerate(ex.layers):
+            firsts.setdefault((seg, elem), i)
         for B in range(1, top + 1):
             h = ex.embed(jnp.zeros((B, S), jnp.int32))
             for k in range(1, B + 1):
                 mask = np.zeros(B, bool)
                 mask[:k] = True
                 h[mask].block_until_ready()
-            jax.block_until_ready(ex.head(ex.run_blocks(h, 0, 1)))
+            for i in firsts.values():
+                h = ex.run_blocks(h, i, i + 1)
+            jax.block_until_ready(ex.head(h))
         super().warm()
 
     def answers(self) -> dict:
@@ -391,10 +435,10 @@ class WavesDrive:
 
     served = False
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    def __init__(self, config: dict, mix: dict, seed: int, root=None):
         from repro.core import PlannerService
         self.config, self.mix, self.seed = config, mix, seed
-        self.P = deploy.task_profile(config)
+        self.P = deploy.task_profile(config, root)
         self.E = deploy.edge_profile(self.P, config["edge"])
         self.sort_keys = tuple(config["planner"]["waves"])
         self.profile, self.edge = program_profile(self.P), program_edge(self.E)
@@ -452,11 +496,13 @@ class WavesDrive:
         self.service.close()
 
 
-def drive(config: dict, mix: dict, seed: int):
+def drive(config: dict, mix: dict, seed: int, root=None):
+    """The cell's drive; ``root`` is where its configuration's files lie
+    (the model's reference module among them)."""
     if "model" in config:
-        return ServedDrive(config, mix, seed)
+        return ServedDrive(config, mix, seed, root)
     if mix["mode"] == "online":
-        return OnlineDrive(config, mix, seed)
+        return OnlineDrive(config, mix, seed, root)
     if mix["mode"] == "waves":
-        return WavesDrive(config, mix, seed)
+        return WavesDrive(config, mix, seed, root)
     raise ValueError(f"no drive for mix mode {mix['mode']!r}")

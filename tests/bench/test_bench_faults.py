@@ -23,21 +23,14 @@ def _correct(name, seconds=0.5, devices=None, **over):
     return out["correct"], out["compared"]
 
 
-# ---- the served cell ---------------------------------------------------------
-def test_served_sound_run_is_correct():
-    ok, nums = _correct("minitron4b.busy")
-    assert ok, nums
-
-
-def test_served_layer_returning_its_state_unchanged(monkeypatch):
+# ---- the served cells --------------------------------------------------------
+def _state_unchanged(monkeypatch):
     import repro.serving.engine as engine
     monkeypatch.setattr(engine, "_layer_step",
                         lambda spec, ctx, stacked, r, h, vision: h)
-    ok, nums = _correct("minitron4b.busy")
-    assert not ok and nums["logit_err"]["value"] > 0.1
 
 
-def test_served_half_the_batch_left_out(monkeypatch):
+def _half_the_batch_left_out(monkeypatch):
     import repro.serving.server as server
     orig = server.run_partitioned
 
@@ -46,11 +39,9 @@ def test_served_half_the_batch_left_out(monkeypatch):
         out[1::2] = 0.0
         return out
     monkeypatch.setattr(server, "run_partitioned", half)
-    ok, nums = _correct("minitron4b.busy", seconds=1.0)
-    assert not ok and nums["logit_err"]["value"] >= 0.5
 
 
-def test_served_logit_altered_where_produced(monkeypatch):
+def _logit_altered(monkeypatch):
     import repro.serving.server as server
     orig = server.run_partitioned
 
@@ -59,8 +50,44 @@ def test_served_logit_altered_where_produced(monkeypatch):
         out[:, 3, 7] += 1e-3 * np.abs(out).max()
         return out
     monkeypatch.setattr(server, "run_partitioned", nudge)
+
+
+def test_served_sound_run_is_correct():
+    ok, nums = _correct("minitron4b.busy")
+    assert ok, nums
+
+
+def test_served_layer_returning_its_state_unchanged(monkeypatch):
+    _state_unchanged(monkeypatch)
+    ok, nums = _correct("minitron4b.busy")
+    assert not ok and nums["logit_err"]["value"] > 0.1
+
+
+def test_served_half_the_batch_left_out(monkeypatch):
+    _half_the_batch_left_out(monkeypatch)
+    ok, nums = _correct("minitron4b.busy", seconds=1.0)
+    assert not ok and nums["logit_err"]["value"] >= 0.5
+
+
+def test_served_logit_altered_where_produced(monkeypatch):
+    _logit_altered(monkeypatch)
     ok, nums = _correct("minitron4b.busy")
     assert not ok and nums["logit_err"]["value"] > 1e-4
+
+
+@pytest.mark.parametrize("fault,least", [
+    (None, None), (_state_unchanged, 0.1), (_half_the_batch_left_out, 0.5),
+    (_logit_altered, 1e-4)])
+def test_sparse_cell_is_correct_only_when_sound(fault, least, monkeypatch):
+    """The small flushes of ``minitron4b.sparse``: the sound run is
+    correct, and each fault of the served path fails it."""
+    if fault is not None:
+        fault(monkeypatch)
+    ok, nums = _correct("minitron4b.sparse", seconds=2.0)
+    if fault is None:
+        assert ok, nums
+    else:
+        assert not ok and nums["logit_err"]["value"] >= least, nums
 
 
 # ---- the planner cells -------------------------------------------------------

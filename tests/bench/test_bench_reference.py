@@ -43,7 +43,7 @@ def test_deployment_arithmetic_is_the_programs():
                         ).read_text())["model"]
     for mine, theirs in ((deploy.mobilenet_v2_profile(),
                           mobilenet_v2_profile()),
-                         (deploy.dense_prefill_profile(model, 32),
+                         (deploy.decoder_prefill_profile(model, 32),
                           profile_from_arch(ARCHS["minitron-4b"], seq=32))):
         np.testing.assert_array_equal(mine.A, theirs.A)
         np.testing.assert_array_equal(mine.O, theirs.O)
@@ -146,8 +146,9 @@ def test_forward_matches_the_executor():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("norm", "layer"), ("mlp_activation", "relu2"), ("rotary_fraction", 0.5),
-    ("compute_dtype", "bfloat16"), ("matmul_precision", "high")])
+    ("norm", "layer"), ("mlp_activation", "no-such-activation"),
+    ("rotary_fraction", 0.5), ("compute_dtype", "bfloat16"),
+    ("matmul_precision", "high")])
 def test_stated_equations_the_program_does_not_compute_are_refused(key,
                                                                    value):
     """A configuration that states equations or arithmetic the executor
@@ -159,11 +160,56 @@ def test_stated_equations_the_program_does_not_compute_are_refused(key,
 
 
 @pytest.mark.parametrize("key,value", [
-    ("norm", "layer"), ("mlp_activation", "relu2"), ("rotary_fraction", 0.5),
-    ("matmul_precision", "bfloat16")])
+    ("norm", "layer"), ("mlp_activation", "no-such-activation"),
+    ("rotary_fraction", 0.5), ("matmul_precision", "bfloat16"),
+    ("plan", [[[{"kind": "swa", "ffn": "dense", "window": 8}], 1]])])
 def test_reference_refuses_equations_it_does_not_implement(key, value):
     model = dict(MINI, num_layers=1, d_model=128, num_heads=4,
                  num_kv_heads=2, head_dim=32, d_ff=256, vocab_size=512)
     w = weights.make(model, 3)
     with pytest.raises(ValueError, match=key):
         tf.hidden(w, np.zeros((1, 4), np.int32), dict(model, **{key: value}))
+
+
+def test_arch_config_of_minitron_is_the_one_segment_dense_model():
+    """No ``plan`` in the file: the one dense segment the harness always
+    built, field for field."""
+    from repro.configs.base import ArchConfig
+    from bench import sut
+    want = ArchConfig(name="minitron-4b", family="dense",
+                      source="bench configuration", num_layers=32,
+                      d_model=3072, num_heads=24, num_kv_heads=8,
+                      head_dim=128, d_ff=9216, vocab_size=256000,
+                      gated_mlp=False, rope_theta=1e6, norm_eps=1e-5)
+    assert sut.arch_config(MINI) == want
+
+
+def test_arch_config_builds_the_stated_plan():
+    from repro.configs.base import LayerSpec
+    from bench import sut
+    model = dict(MINI, num_layers=3, plan=[
+        [[{"kind": "attn", "ffn": "dense", "window": None}], 1],
+        [[{"kind": "swa", "ffn": "dense", "window": 8}], 2]])
+    cfg = sut.arch_config(model)
+    assert cfg.layer_sequence() == [LayerSpec("attn", "dense"),
+                                     LayerSpec("swa", "dense", 8),
+                                     LayerSpec("swa", "dense", 8)]
+
+
+def test_the_programs_own_table_is_read_where_it_has_one(monkeypatch):
+    """A program that states what its executor computes, next to
+    ``BlockwiseExecutor``, is held to that table and not to the harness's
+    record of it."""
+    import repro.serving.engine as engine
+    from bench import sut
+    model = dict(MINI, mlp_activation="no-such-activation")
+    with pytest.raises(ValueError, match="mlp_activation"):
+        sut.arch_config(model)
+    monkeypatch.setattr(engine, "PROGRAM_ACTIVATION",
+                        {False: ("gelu_tanh", "no-such-activation"),
+                         True: "silu"}, raising=False)
+    assert sut.arch_config(model).name == "minitron-4b"
+    monkeypatch.setattr(engine, "PROGRAM", dict(sut.PROGRAM, norm=("layer",)),
+                        raising=False)
+    with pytest.raises(ValueError, match="norm"):
+        sut.arch_config(model)
