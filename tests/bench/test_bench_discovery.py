@@ -1,60 +1,33 @@
 """Configurations, mixes and per-layer metrics are found by file name,
 and BENCHMARK.json keeps the contract's shape."""
 import json
-import re
 import shutil
 from types import SimpleNamespace
 
 import benchtest_util
 import pytest
+import spec_checks
 
 from bench import run
 
 ROOT = benchtest_util.ROOT
 SPEC = run.load_spec()
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def test_every_cell_resolves_its_files():
-    for cell in SPEC["workloads"]:
-        c, config, mix = run.cell_parts(SPEC, cell["name"])
-        assert config["name"] == cell["config"]
-        assert mix["mode"] in ("online", "waves")
-        assert set(config["limits"]) >= {"plan_energy_gap", "deadline_excess"}
+    spec_checks.cells_resolve_their_files(SPEC, ROOT)
 
 
 def test_every_metric_has_a_reader():
-    for m in SPEC["per_layer"]:
-        assert callable(run.reader(m["name"]))
-        assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
+    spec_checks.metrics_have_readers(SPEC, ROOT)
 
 
 def test_names_and_paths_keep_the_contract():
-    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
-                         "workloads", "end_to_end", "per_layer"}
-    names = [x["name"] for k in ("configs", "workloads", "end_to_end",
-                                 "per_layer") for x in SPEC[k]]
-    assert len(names) == len(set(names))
-    assert all(NAME.match(n) for n in names)
-    for c in SPEC["configs"]:
-        assert c["file"].startswith("bench/")
-        assert json.loads((ROOT / c["file"]).read_text())["reduced"] == \
-            c["reduced"]
-    for m in SPEC["end_to_end"]:
-        assert m["bound"] <= 0.25
-    for x in SPEC["configs"] + SPEC["workloads"]:
-        assert 1 <= len(x["why"]) <= 200 and "\n" not in x["why"]
-    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
-        assert re.match(r"^[A-Za-z0-9_/%.-]{1,16}$", m["unit"])
-        assert m["better"] in ("lower", "higher")
-    assert {m["name"] for m in SPEC["end_to_end"]} == {
-        "flush_ms.p50", "flush_ms.p95", "req_per_s", "setup_s"}
+    spec_checks.names_and_paths_keep_the_contract(SPEC, ROOT)
 
 
 def test_each_cell_reports_a_per_layer_metric():
-    for cell in SPEC["workloads"]:
-        assert run.metrics_of(SPEC, cell, True)
-        assert len(run.metrics_of(SPEC, cell, False)) == 4
+    spec_checks.cells_report_a_per_layer_metric(SPEC, ROOT)
 
 
 def test_a_new_cell_is_files_and_entries_alone(tmp_path):
@@ -170,6 +143,47 @@ def test_a_model_configuration_is_files_and_entries_alone(tmp_path):
     by = flops.layers_roofline_s(model, B, S, peaks("TPU v5 lite"), tmp_path)
     assert by["compute"] + by["memory"] > 0
     assert _digest(ROOT / "bench") == before
+
+
+def test_a_served_model_joins_the_real_spec_as_files_and_entries(tmp_path):
+    """The repository's own BENCHMARK.json and bench/, joined by a served
+    configuration of another layer plan as new files (its reference
+    module and configuration) and entries (its cell on busy, named in
+    every per-layer list that names minitron4b.busy), pass every check
+    the tests run on the spec: the old formulas on their own
+    configurations' cells, the generic ones on every served cell."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "tests" / "bench" / "windowed_reference.py",
+                tmp_path / "bench" / "reference" / "extra_windowed.py")
+    cfg = json.loads((ROOT / "bench/configs/minitron4b-coinf.json"
+                      ).read_text())
+    # embedding and head 2·V·d, and two layers of attention 2·d·(H + KV)·hd,
+    # MLP 2·d·ff and two norm scales of d: 131,072 + 2 · 114,944; the final
+    # norm is left out, as in every configuration's params
+    params = 360_960
+    cfg.update(name="extra-windowed", reduced=[],
+               model=dict(cfg["model"], **benchtest_util.TINY_MODEL,
+                          arch="windowed", reference="extra_windowed",
+                          plan=WINDOWED_PLAN, params=params))
+    del cfg["departs"]
+    (tmp_path / "bench/configs/extra-windowed.json").write_text(
+        json.dumps(cfg))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": "extra-windowed", "source": "x",
+                            "file": "bench/configs/extra-windowed.json",
+                            "reduced": [], "why": "x"})
+    spec["workloads"].append({"name": "extra-windowed.busy",
+                              "config": "extra-windowed", "traffic": "busy",
+                              "chips": 1, "why": "x"})
+    for m in spec["per_layer"]:
+        if "minitron4b.busy" in m.get("workloads", []):
+            m["workloads"].append("extra-windowed.busy")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    spec = run.load_spec(tmp_path)
+    assert "extra-windowed.busy" in spec_checks.served_cells(spec, tmp_path)
+    assert "extra-windowed.busy" not in spec_checks.old_formula_cells(spec)
+    spec_checks.run_all(spec, tmp_path)
 
 
 def test_unknown_cell_is_an_error():

@@ -1,7 +1,9 @@
 """A served model's weights, task profile, fleet and FLOP counts come from
-the reference module its configuration names, and for the cells already
-in BENCHMARK.json they are what the harness computed before it looked the
-module up: the old formulas are written out here."""
+the reference module its configuration names.  For the configurations
+they were written for (minitron4b-coinf and mnv2-paper) they are what the
+harness computed before it looked the module up: the old formulas are
+written out here and in ``spec_checks``.  Every served cell is also
+checked against its own module."""
 import json
 
 import benchtest_util
@@ -9,8 +11,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import spec_checks
 
-from bench import deploy, flops, run, traffic, weights
+from bench import flops, run, traffic, weights
 from bench.reference import model_module
 from bench.reference import transformer as tf
 
@@ -20,23 +23,6 @@ MINI = json.loads((ROOT / "bench/configs/minitron4b-coinf.json").read_text())
 DENSE_PATHS = ["['embed']['w']", "['final_norm']", "['lm_head']['w']"] + [
     f"['segments'][0][0]['{k}']" for k in (
         "norm1", "norm2", "w_down", "w_up", "wk", "wo", "wq", "wv")]
-
-
-def old_dense_prefill(model: dict, seq: int, act_bytes: int):
-    """The harness's profile of a dense decoder before counts were per
-    layer."""
-    d, H, KV = model["d_model"], model["num_heads"], model["num_kv_heads"]
-    hd = model["head_dim"]
-    qkv = 2.0 * seq * d * (H * hd + 2 * KV * hd)
-    out = 2.0 * seq * H * hd * d
-    attn = 2.0 * 2.0 * seq * (seq / 2.0) * H * hd
-    mlp = 2.0 * seq * d * model["d_ff"] * (3 if model["gated_mlp"] else 2)
-    L = model["num_layers"]
-    A = [0.0] + [qkv + out + attn + mlp] * L
-    O = [float(seq * 4)] + [float(seq * d * act_bytes)] * L
-    A[-1] += 2.0 * seq * d * model["vocab_size"]
-    O[-1] = float(seq * model["vocab_size"] * act_bytes)
-    return np.asarray(A), np.asarray(O)
 
 
 def old_dense_weights(model: dict, seed: int):
@@ -71,9 +57,7 @@ def old_dense_weights(model: dict, seed: int):
     return draw(key)
 
 
-def _served_cells():
-    return [c["name"] for c in SPEC["workloads"]
-            if "model" in run.cell_parts(SPEC, c["name"])[1]]
+SERVED = spec_checks.served_cells(SPEC, ROOT)
 
 
 def test_a_configuration_without_reference_names_the_transformer():
@@ -88,34 +72,28 @@ def test_a_reference_that_is_not_a_module_file_is_an_error(name):
         model_module(dict(MINI["model"], reference=name))
 
 
-@pytest.mark.parametrize("cell", [c["name"] for c in SPEC["workloads"]])
+@pytest.mark.parametrize("cell", spec_checks.old_formula_cells(SPEC))
 def test_each_cells_profile_and_fleet_are_the_old_ones(cell):
-    _, config, mix = run.cell_parts(SPEC, cell)
-    P = deploy.task_profile(config)
-    if "model" in config:
-        task = config["task"]
-        A, O = old_dense_prefill(config["model"], task["seq"],
-                                 task["act_bytes"])
-    else:
-        old = deploy.mobilenet_v2_profile(config["task"]["input_res"],
-                                          config["task"]["act_bytes"])
-        A, O = old.A, old.O
-    assert P.A.tobytes() == A.tobytes() and P.O.tobytes() == O.tobytes()
-    old_p = deploy.Profile(P.name, A, O, np.ones_like(A), np.ones_like(A))
-    beta = traffic.device_betas(mix, 2 ** 34 + 3) if mix["mode"] == "online" \
-        else traffic.wave_betas(mix, 2 ** 34 + 3, 0)
-    E = deploy.edge_profile(P, config["edge"])
-    new_fl = deploy.fleet(P, E, config["fleet"], beta)
-    old_fl = deploy.fleet(old_p, deploy.edge_profile(old_p, config["edge"]),
-                          config["fleet"], beta)
-    for k in new_fl:
-        assert new_fl[k].tobytes() == old_fl[k].tobytes(), k
+    spec_checks.profile_and_fleet_are_the_old_ones(SPEC, cell, ROOT)
 
 
 def test_served_cells_are_minitron_on_the_transformer():
-    assert _served_cells() == ["minitron4b.busy", "minitron4b.sparse"]
-    for cell in _served_cells():
-        assert run.cell_parts(SPEC, cell)[1]["model"] == MINI["model"]
+    spec_checks.minitron_cells_run_on_the_transformer(SPEC, ROOT)
+
+
+@pytest.mark.parametrize("cell", SERVED)
+def test_each_served_weight_tree_holds_the_files_params(cell):
+    spec_checks.weight_tree_holds_params(SPEC, cell, ROOT)
+
+
+@pytest.mark.parametrize("cell", SERVED)
+def test_each_served_profile_is_the_modules_block_flops(cell):
+    spec_checks.profile_is_the_modules_block_flops(SPEC, cell, ROOT)
+
+
+@pytest.mark.parametrize("cell", SERVED)
+def test_each_served_forward_flops_are_layer_sums(cell):
+    spec_checks.forward_flops_are_layer_sums(SPEC, cell, ROOT)
 
 
 def test_dense_weight_tree_keeps_its_leaf_paths_and_order():
